@@ -24,10 +24,6 @@ import json
 import os
 import time
 
-from repro.launch.mesh import ensure_host_devices
-
-ensure_host_devices(4)
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +32,8 @@ from repro.core import paper_queries as PQ
 from repro.core.faults import FaultEvent, FaultPlan
 from repro.core.recovery import RecoveryConfig
 from repro.core.session import ExecutionConfig
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import ensure_host_devices
 
 from .common import build_world, format_table, make_session
 
@@ -171,6 +169,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=DEFAULT_SEED,
                     help="FaultPlan seed (the CI job pins this)")
     args = ap.parse_args(argv)
+    ensure_host_devices(4)
+    setup_compile_cache()
     run(seed=args.seed)
 
 

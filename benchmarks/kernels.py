@@ -1,7 +1,7 @@
 """Pallas kernel micro-benchmarks: fidelity + shape sweeps vs ref oracles.
 
-This container executes kernels in ``interpret=True`` mode (Python on CPU),
-so wall-clock here is NOT TPU performance — the numbers that matter for the
+On the CPU backend the kernels run through the Pallas interpreter, so
+wall-clock there is NOT TPU performance — the numbers that matter for the
 kernels are the roofline terms in EXPERIMENTS.md §Roofline.  What this bench
 certifies per kernel: (a) allclose vs the pure-jnp oracle at benchmark
 shapes, (b) the jnp fallback's wall time (the path XLA actually runs on CPU),
@@ -133,13 +133,12 @@ def bench_join_fused():
     *before* — the engine's unfused scan join: materialize the [M, N] match
     matrix, broadcast the [M, N, nv] row extension, compact M*N rows.
     *after* — the fused jnp path (the formulation XLA executes on this CPU
-    host; identical algorithm to the Pallas kernel's count+scatter phases).
-    The Pallas fused kernel itself runs in interpret mode here, so it is
-    checked for bit-exactness but timed only as the jnp twin; the in-kernel
-    scatter's Mosaic lowering must be validated before flipping
-    ``interpret=False`` on real hardware (see hash_join/kernel.py), where
-    the fusion targets the HBM-traffic ratio (O(M*N) -> O(M*N read-once +
-    out_cap)).
+    host; it finds the same first ``out_cap`` matches as the Pallas
+    kernel's count+emit phases).  On the CPU backend the Pallas fused
+    kernel runs through the interpreter, so it is checked for
+    bit-exactness but timed only as the jnp twin; on a TPU it is compiled,
+    where the fusion targets the HBM-traffic ratio (O(M*N) -> O(M*N
+    read-once + out_cap)).
     """
     from repro.core import algebra
 
@@ -348,4 +347,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

@@ -29,18 +29,13 @@ import os
 import time
 from typing import Optional
 
-# Force a multi-device CPU backend BEFORE jax initializes: round_robin
-# placement can only spread enrichment operators across devices when the
-# host platform exposes more than one.  Honors a caller-provided flag.
-from repro.launch.mesh import ensure_host_devices
-
-ensure_host_devices(4)
-
 import jax
 import numpy as np
 
 from repro.core import paper_queries as PQ
 from repro.core.session import ExecutionConfig
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import ensure_host_devices
 
 from .common import build_world, format_table, make_session
 
@@ -436,6 +431,11 @@ def main(argv=None):
                          "(the kb_access section always compares all three "
                          "on the monolithic full-KB runtime)")
     args = ap.parse_args(argv)
+    # on the CPU, a multi-device backend BEFORE jax initializes: round_robin
+    # placement can only spread enrichment operators across devices when
+    # the host platform exposes more than one
+    ensure_host_devices(4)
+    setup_compile_cache()
     run(iters=args.iters, smoke=args.smoke, query=args.query,
         kb_method=args.kb_method)
 

@@ -122,4 +122,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

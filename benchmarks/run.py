@@ -33,6 +33,11 @@ def main(argv=None) -> int:
     p.add_argument("--only", default="step1,step2,step3,kernels,pipeline,roofline")
     p.add_argument("--iters", type=int, default=3)
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    from repro.launch.mesh import ensure_host_devices
+
+    ensure_host_devices(4)
+    setup_compile_cache()
     want = [s.strip() for s in args.only.split(",") if s.strip()]
 
     failures = []

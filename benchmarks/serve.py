@@ -26,6 +26,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.session import ExecutionConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.dscep_run import serve_population
 
 from .common import build_world, format_table, make_session
@@ -173,6 +174,7 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=None,
                     help="timing iterations (default: 2, or 1 with --smoke)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     run(iters=args.iters, smoke=args.smoke)
 
 

@@ -93,4 +93,7 @@ def run(world: BenchWorld = None, iters: int = 5) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

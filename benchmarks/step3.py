@@ -150,4 +150,8 @@ def run(sweep: str = "both", iters: int = 3) -> dict:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run(sys.argv[1] if len(sys.argv) > 1 else "both")

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.planner import plan_supports_delta
 from repro.core.session import ExecutionConfig
+from repro.launch.compile_cache import setup_compile_cache
 
 from .common import build_world, format_table, make_session
 from .pipeline import _throughput
@@ -176,6 +177,7 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=None,
                     help="timing iterations (default: 3, or 1 with --smoke)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     run(iters=args.iters, smoke=args.smoke)
 
 

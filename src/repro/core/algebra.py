@@ -38,7 +38,9 @@ import jax.numpy as jnp
 from repro.obs.metrics import stat_max
 
 from .kb import KnowledgeBase, gather_matches, probe_range
-from .pattern import Bindings, CompiledPattern, SlotMode, compact_rows
+from .pattern import (
+    Bindings, CompiledPattern, SlotMode, compact_index, compact_rows,
+)
 from .rdf import NUM_BASE, PAD_ID, TripleBatch, composite_key
 
 
@@ -87,16 +89,34 @@ def scan_pattern(
 # natural join (used by BGP conjunction and by the final aggregation operator)
 # --------------------------------------------------------------------------
 
-def join(a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int) -> Bindings:
-    """Natural join on the static shared-variable columns."""
-    ca, cb = a.capacity, b.capacity
+def _pair_mask(a: Bindings, b: Bindings, shared: Tuple[int, ...]):
     m = a.valid[:, None] & b.valid[None, :]
     for c in shared:
         m = m & (a.cols[:, None, c] == b.cols[None, :, c])
-    merged = jnp.maximum(a.cols[:, None, :], b.cols[None, :, :])  # PAD=0 ⇒ max merges
-    flat_rows = merged.reshape(ca * cb, a.num_vars)
-    flat_mask = m.reshape(ca * cb)
-    rows, valid, overflow = compact_rows(flat_rows, flat_mask, out_cap)
+    return m
+
+
+def _merge_pairs(a: Bindings, b: Bindings, src: jax.Array) -> jax.Array:
+    """Row ``src = i * cb + j`` of the virtual ``[ca * cb]`` pair table:
+    ``a[i]`` merged with ``b[j]`` (PAD=0, so max merges)."""
+    cb = b.capacity
+    return jnp.maximum(jnp.take(a.cols, src // cb, axis=0, mode="clip"),
+                       jnp.take(b.cols, src % cb, axis=0, mode="clip"))
+
+
+def _zero_invalid(rows: jax.Array, valid: jax.Array) -> jax.Array:
+    return jnp.where(valid[:, None], rows, jnp.zeros_like(rows))
+
+
+def join(a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int) -> Bindings:
+    """Natural join on the static shared-variable columns.
+
+    Bit-identical to compacting the row-major ``[ca * cb]`` table of merged
+    pairs, but only the ``out_cap`` winning pairs are ever merged.
+    """
+    m = _pair_mask(a, b, shared)
+    src, valid, overflow = compact_index(m.reshape(-1), out_cap)
+    rows = _zero_invalid(_merge_pairs(a, b, src), valid)
     return Bindings(rows, valid, overflow | a.overflow | b.overflow)
 
 
@@ -110,18 +130,19 @@ def union(a: Bindings, b: Bindings, out_cap: int) -> Bindings:
 def optional_join(
     a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int
 ) -> Bindings:
-    """SPARQL OPTIONAL: left outer join; unmatched left rows keep PAD columns."""
-    ca, cb = a.capacity, b.capacity
-    m = a.valid[:, None] & b.valid[None, :]
-    for c in shared:
-        m = m & (a.cols[:, None, c] == b.cols[None, :, c])
-    matched_any = jnp.any(m, axis=1)
-    merged = jnp.maximum(a.cols[:, None, :], b.cols[None, :, :])
-    flat_rows = jnp.concatenate(
-        [merged.reshape(ca * cb, a.num_vars), a.cols], axis=0
-    )
-    flat_mask = jnp.concatenate([m.reshape(ca * cb), a.valid & ~matched_any], axis=0)
-    rows, valid, overflow = compact_rows(flat_rows, flat_mask, out_cap)
+    """SPARQL OPTIONAL: left outer join; unmatched left rows keep PAD columns.
+
+    The candidate table is the merged pairs followed by the left rows; as
+    in :func:`join`, only the ``out_cap`` winners are built.
+    """
+    npairs = a.capacity * b.capacity
+    m = _pair_mask(a, b, shared)
+    flat_mask = jnp.concatenate(
+        [m.reshape(npairs), a.valid & ~jnp.any(m, axis=1)], axis=0)
+    src, valid, overflow = compact_index(flat_mask, out_cap)
+    left = jnp.take(a.cols, src - npairs, axis=0, mode="clip")
+    rows = jnp.where((src < npairs)[:, None], _merge_pairs(a, b, src), left)
+    rows = _zero_invalid(rows, valid)
     return Bindings(rows, valid, overflow | a.overflow | b.overflow)
 
 
@@ -164,7 +185,6 @@ def kb_join_scan(
     bind: Bindings, kb: KnowledgeBase, pat: CompiledPattern, out_cap: int,
     use_pallas: bool = False, fuse_compaction: bool = False,
     bm: Optional[int] = None, bn: Optional[int] = None,
-    interpret: bool = True,
 ) -> Bindings:
     """Join bindings against a KB partition by full scan.
 
@@ -182,13 +202,11 @@ def kb_join_scan(
     if fuse_compaction:
         from repro.kernels.hash_join import ops as hj_ops
         if use_pallas:
-            return hj_ops.join_compact(bind, kb, pat, out_cap, bm=bm, bn=bn,
-                                       interpret=interpret)
+            return hj_ops.join_compact(bind, kb, pat, out_cap, bm=bm, bn=bn)
         return hj_ops.join_compact_jnp(bind, kb, pat, out_cap)
     if use_pallas:
         from repro.kernels.hash_join import ops as hj_ops
-        m = hj_ops.match_matrix(bind, kb, pat, bm=bm, bn=bn,
-                                interpret=interpret)
+        m = hj_ops.match_matrix(bind, kb, pat, bm=bm, bn=bn)
     else:
         m = _kb_scan_match(bind, kb, pat)
     ca, n = m.shape
@@ -226,7 +244,7 @@ def _probe_width_hw(bind: Bindings, kb: KnowledgeBase, pat: CompiledPattern):
 def kb_join_probe(
     bind: Bindings, kb: KnowledgeBase, pat: CompiledPattern, out_cap: int,
     k_max: int = 8, use_pallas: bool = False, fuse_compaction: bool = False,
-    bm: Optional[int] = None, interpret: bool = True,
+    bm: Optional[int] = None,
     stats: Optional[Dict[str, Any]] = None,
 ) -> Bindings:
     """Join bindings against the KB via sorted-index probes.
@@ -248,8 +266,7 @@ def kb_join_probe(
             stat_max(stats, "hw_probe_k", _probe_width_hw(bind, kb, pat))
         from repro.kernels.hash_join import ops as hj_ops
         if use_pallas:
-            return hj_ops.probe_compact(bind, kb, pat, out_cap, k_max,
-                                        bm=bm, interpret=interpret)
+            return hj_ops.probe_compact(bind, kb, pat, out_cap, k_max, bm=bm)
         return hj_ops.probe_compact_jnp(bind, kb, pat, out_cap, k_max)
 
     from .kb import probe_view
@@ -293,7 +310,7 @@ def kb_join(
     bind: Bindings, kb: KnowledgeBase, pat: CompiledPattern, out_cap: int,
     method: str = "scan", k_max: int = 8, use_pallas: bool = False,
     fuse_compaction: bool = False, bm: Optional[int] = None,
-    bn: Optional[int] = None, interpret: bool = True,
+    bn: Optional[int] = None,
     stats: Optional[Dict[str, Any]] = None,
 ) -> Bindings:
     """Dispatch one KB join to its access method.
@@ -312,10 +329,9 @@ def kb_join(
         return kb_join_probe(bind, kb, pat, out_cap, k_max,
                              use_pallas=use_pallas,
                              fuse_compaction=fuse_compaction, bm=bm,
-                             interpret=interpret, stats=stats)
+                             stats=stats)
     return kb_join_scan(bind, kb, pat, out_cap, use_pallas=use_pallas,
-                        fuse_compaction=fuse_compaction, bm=bm, bn=bn,
-                        interpret=interpret)
+                        fuse_compaction=fuse_compaction, bm=bm, bn=bn)
 
 
 # --------------------------------------------------------------------------
@@ -445,10 +461,22 @@ def canonical_order(bind: Bindings, sig_cols: Tuple[int, ...]) -> Bindings:
     itself differs between a monolithic plan and a decomposed aggregator.
     Applied after the pre-CONSTRUCT distinct, where rows are the
     deduplicated projection onto template variables.
+
+    The stable sort is a rank count over all row pairs (``[cap, cap]``
+    compares) rather than a many-operand sort, which takes the TPU
+    compiler tens of seconds at real capacities.
     """
-    keys = tuple(bind.cols[:, c] for c in reversed(sig_cols))
-    inv = (~bind.valid).astype(jnp.uint32)
-    order = jnp.lexsort(keys + (inv,))
+    keys = [(~bind.valid).astype(jnp.uint32)]
+    keys += [bind.cols[:, c] for c in sig_cols]
+    idx = jnp.arange(bind.capacity, dtype=jnp.int32)
+    # before[i, j]: row j sorts before row i (ties keep the original order)
+    before = idx[None, :] < idx[:, None]
+    for k in reversed(keys):
+        before = (k[None, :] < k[:, None]) | ((k[None, :] == k[:, None])
+                                              & before)
+    rank = jnp.sum(before.astype(jnp.int32), axis=1)
+    order = jnp.sum(jnp.where(rank[None, :] == idx[:, None], idx[None, :], 0),
+                    axis=1)
     return Bindings(
         jnp.take(bind.cols, order, axis=0), jnp.take(bind.valid, order),
         bind.overflow,
@@ -456,23 +484,18 @@ def canonical_order(bind: Bindings, sig_cols: Tuple[int, ...]) -> Bindings:
 
 
 def distinct(bind: Bindings, out_cap: Optional[int] = None) -> Bindings:
-    """Deduplicate valid rows (order of first occurrence preserved)."""
+    """Deduplicate valid rows (order of first occurrence preserved).
+
+    A valid row is kept unless an earlier valid row equals it: ``[cap, cap]``
+    compares, no sort.
+    """
     out_cap = out_cap or bind.capacity
-    nv = bind.num_vars
-    # lexsort by columns with invalids last, stable on original index
-    keys = [bind.cols[:, c] for c in range(nv - 1, -1, -1)]
-    inv = (~bind.valid).astype(jnp.uint32)
-    order = jnp.lexsort(tuple(keys) + (inv,))
-    sorted_cols = jnp.take(bind.cols, order, axis=0)
-    sorted_valid = jnp.take(bind.valid, order)
-    prev = jnp.concatenate([jnp.zeros((1, nv), jnp.uint32), sorted_cols[:-1]], axis=0)
-    first_at0 = jnp.arange(bind.capacity) == 0
-    is_new = jnp.any(sorted_cols != prev, axis=1) | first_at0
-    keep = sorted_valid & is_new
-    # restore original order for determinism
-    restore = jnp.argsort(order)
-    keep_orig = jnp.take(keep, restore)
-    rows, valid, overflow = compact_rows(bind.cols, keep_orig, out_cap)
+    idx = jnp.arange(bind.capacity, dtype=jnp.int32)
+    dup = bind.valid[None, :] & (idx[None, :] < idx[:, None])
+    for c in range(bind.num_vars):
+        dup = dup & (bind.cols[None, :, c] == bind.cols[:, None, c])
+    keep = bind.valid & ~jnp.any(dup, axis=1)
+    rows, valid, overflow = compact_rows(bind.cols, keep, out_cap)
     return Bindings(rows, valid, overflow | bind.overflow)
 
 

@@ -43,7 +43,6 @@ class KBJoin:
     fuse_compaction: bool = False  # fused join->compaction (no [M, N] in HBM)
     bm: Optional[int] = None       # fused-kernel block shapes (None = autotune)
     bn: Optional[int] = None
-    interpret: bool = True         # Pallas interpret mode (False on real TPU)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,7 +213,7 @@ def _apply(
             cur, kb, step.pat, plan.bind_cap, method=step.method,
             k_max=step.k_max, use_pallas=step.use_pallas,
             fuse_compaction=step.fuse_compaction, bm=step.bm, bn=step.bn,
-            interpret=step.interpret, stats=stats,
+            stats=stats,
         )
     if isinstance(step, FilterNumStep):
         return algebra.filter_num(cur, step.var, step.op, step.value_id)
@@ -401,7 +400,7 @@ def _apply_delta(
             cur, kb, step.pat, plan.bind_cap, method=step.method,
             k_max=step.k_max, use_pallas=step.use_pallas,
             fuse_compaction=step.fuse_compaction, bm=step.bm, bn=step.bn,
-            interpret=step.interpret, stats=stats,
+            stats=stats,
         )
     if isinstance(step, FilterNumStep):
         return algebra.filter_num(cur, step.var, step.op, step.value_id)

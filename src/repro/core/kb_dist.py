@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from . import algebra
 from .kb import KnowledgeBase
 from .pattern import Bindings, CompiledPattern
@@ -44,7 +42,6 @@ def kb_join_sharded(
     fuse_compaction: bool = False,
     bm: int | None = None,
     bn: int | None = None,
-    interpret: bool = True,
 ) -> Bindings:
     """Join replicated bindings against a row-sharded KB partition.
 
@@ -62,14 +59,13 @@ def kb_join_sharded(
         b = Bindings(cols, valid, overflow)
         out = algebra.kb_join(b, kb_local, pat, per_cap, method=method,
                               k_max=k_max, use_pallas=use_pallas,
-                              fuse_compaction=fuse_compaction, bm=bm, bn=bn,
-                              interpret=interpret)
+                              fuse_compaction=fuse_compaction, bm=bm, bn=bn)
         # overflow is global info: reduce the one bool over the KB axis
         ovf = jax.lax.psum(out.overflow.astype(jnp.int32), axis) > 0
         return out.cols[None], out.valid[None], ovf
 
     kb_spec = jax.tree.map(lambda _: P(axis), kb_blocks)
-    cols, valid, overflow = shard_map(
+    cols, valid, overflow = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(), kb_spec),
         out_specs=(P(axis), P(axis), P()),

@@ -97,6 +97,30 @@ def universe_bindings(capacity: int, num_vars: int) -> Bindings:
     return b._replace(valid=b.valid.at[0].set(True))
 
 
+_PREFIX_BLOCK = 128
+
+
+def prefix_count(mask: jax.Array) -> jax.Array:
+    """Inclusive running count of a 1-D bool ``mask``, as int32.
+
+    Equal to ``cumsum(mask)``, computed in two levels: a triangular-ones
+    matmul within 128-wide blocks (0/1 inputs and counts up to 128 are exact
+    in bf16 x bf16 -> f32), plus a cumsum over the block totals.  A plain
+    cumsum over a million entries takes the TPU compiler over 20 s; this
+    takes under one.
+    """
+    n = mask.shape[0]
+    b = _PREFIX_BLOCK
+    nb = -(-n // b)
+    blocks = jnp.pad(mask, (0, nb * b - n)).reshape(nb, b)
+    tri = jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]
+    within = jnp.dot(blocks.astype(jnp.bfloat16), tri.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    totals = within[:, -1]
+    before = jnp.cumsum(totals) - totals
+    return (within + before[:, None]).reshape(nb * b)[:n]
+
+
 def compact_rows(
     rows: jax.Array, mask: jax.Array, out_cap: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -105,7 +129,7 @@ def compact_rows(
     Returns ``(rows_out [out_cap, ...], valid [out_cap], overflow [])``.
     """
     n = rows.shape[0]
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    pos = prefix_count(mask) - 1
     total = jnp.sum(mask.astype(jnp.int32))
     tgt = jnp.where(mask & (pos < out_cap), pos, out_cap)
     idx = jnp.full((out_cap + 1,), -1, jnp.int32)
@@ -118,3 +142,22 @@ def compact_rows(
         valid.reshape((out_cap,) + (1,) * (rows.ndim - 1)), out, jnp.zeros_like(out)
     )
     return out, valid, total > out_cap
+
+
+def compact_index(
+    mask: jax.Array, out_cap: int
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Where :func:`compact_rows` would take its rows from, without rows.
+
+    Returns ``(src [out_cap] int32, valid [out_cap], overflow [])``: the
+    first ``min(count, out_cap)`` set positions of the flat ``mask``, in
+    order (``src`` past them points anywhere in range).  For a mask over a
+    virtual product, the caller gathers only the ``out_cap`` winning rows
+    instead of materializing every candidate row.
+    """
+    cum = prefix_count(mask)
+    total = cum[-1]
+    k = jnp.arange(out_cap, dtype=jnp.int32)
+    src = jnp.searchsorted(cum, k + 1, side="left").astype(jnp.int32)
+    src = jnp.minimum(src, mask.shape[0] - 1)
+    return src, k < jnp.minimum(total, out_cap), total > out_cap

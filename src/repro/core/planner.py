@@ -88,8 +88,7 @@ def _host_reach_sets(edges: Sequence[Tuple[int, int]]) -> Dict[int, Set[int]]:
 
 
 def _kernel_reach_set(
-    edges: Sequence[Tuple[int, int]], root: int, interpret: bool,
-    ancestors: bool,
+    edges: Sequence[Tuple[int, int]], root: int, ancestors: bool,
 ) -> Set[int]:
     """One root's closure set via the fused descendants/ancestors kernel."""
     from repro.kernels.closure import ops as cl_ops
@@ -99,15 +98,14 @@ def _kernel_reach_set(
         return {root}
     adj = adjacency_from_edges(edges, idx)
     op = cl_ops.closure_ancestors if ancestors else cl_ops.closure_descendants
-    got, count = op(np.asarray(adj), idx[root], out_cap=len(ids),
-                    interpret=interpret)
+    got, count = op(np.asarray(adj), idx[root], out_cap=len(ids))
     sel = np.asarray(got)[: int(count)]
     return {int(v) for v in ids[sel]}
 
 
 def _closure_pairs(
     edges: Sequence[Tuple[int, int]], min_hops: int,
-    uses: Sequence[Q.PathClosure], use_pallas: bool, interpret: bool,
+    uses: Sequence[Q.PathClosure], use_pallas: bool,
 ) -> Set[Tuple[int, int]]:
     """The pair relation ``{(x, y) : x pred^n y, n >= min_hops}``.
 
@@ -141,7 +139,7 @@ def _closure_pairs(
         roots = {int(getattr(u, anchor).id) for u in uses}
         for root in sorted(roots):
             if use_pallas:
-                star = _kernel_reach_set(edges, root, interpret,
+                star = _kernel_reach_set(edges, root,
                                          ancestors=not const_end)
             elif const_end:
                 star = {int(v) for v in descendants(edges, root)}
@@ -168,8 +166,7 @@ def _closure_pairs(
 
         adj = adjacency_from_edges(edges, idx)
         reach = np.asarray(cl_ops.transitive_closure(
-            jnp.asarray(adj), max_depth=len(idx), use_pallas=True,
-            interpret=interpret))
+            jnp.asarray(adj), max_depth=len(idx), use_pallas=True))
         if min_hops == 1:
             reach = (adj @ reach.astype(np.float32)) > 0.5
         pairs |= {(int(ids[i]), int(ids[j]))
@@ -187,7 +184,7 @@ def _closure_pairs(
 
 def augment_kb_with_closures(
     q: Q.Query, kb: KnowledgeBase,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool = False,
 ) -> KnowledgeBase:
     """Materialize every variable-length path of ``q`` as closure-pair rows.
 
@@ -211,7 +208,7 @@ def augment_kb_with_closures(
                 and (it.pred, it.min_hops) == (pid, min_hops)]
         m = rows[:, 1] == np.uint32(pid)
         edges = [(int(s), int(o)) for s, _, o in rows[m]]
-        pairs = _closure_pairs(edges, min_hops, uses, use_pallas, interpret)
+        pairs = _closure_pairs(edges, min_hops, uses, use_pallas)
         cp = CLOSURE_PRED_BASE + i
         out_rows.extend((x, cp, y) for x, y in sorted(pairs))
     return kb_from_triples(out_rows)
@@ -441,7 +438,6 @@ def compile_query(
     fuse_compaction: bool = False,
     join_bm: int | None = None,
     join_bn: int | None = None,
-    interpret: bool = True,
     kb_stats: Optional[KBStats] = None,
 ) -> Plan:
     """Compile the AST into a Plan.
@@ -474,7 +470,7 @@ def compile_query(
         if kb_method == "auto":
             method, k = _choose_kb_method(cp, kb_stats, k_max)
         return KBJoin(cp, method, k, use_pallas, fuse_compaction,
-                      join_bm, join_bn, interpret)
+                      join_bm, join_bn)
 
     def fresh_aux() -> str:
         aux[0] += 1
@@ -1002,29 +998,26 @@ def explain_plan(
 
 def prepare_env(
     q: Q.Query, kb: KnowledgeBase,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool = False,
 ) -> Dict[str, np.ndarray]:
     """Compute closure sets required by the query's reasoning filters.
 
     ``use_pallas=True`` computes each subclass closure with the fused
     Pallas closure kernel (:func:`repro.kernels.closure.ops.closure_descendants`)
-    instead of the host-side BFS — ``interpret`` selects the kernel's
-    interpreter vs real-accelerator compilation (the config-plumbed knob).
-    Both paths produce the identical sorted id set.
+    instead of the host-side BFS.  Both paths produce the identical sorted id set.
     """
     env: Dict[str, np.ndarray] = {}
     for item in q.where:
         if isinstance(item, Q.FilterSubclass):
             key, arr = closure_env_entry(
-                kb, item.subclass_pred, item.super_class, use_pallas,
-                interpret)
+                kb, item.subclass_pred, item.super_class, use_pallas)
             env[key] = arr
     return env
 
 
 def closure_env_entry(
     kb: KnowledgeBase, subclass_pred: int, super_class: int,
-    use_pallas: bool = False, interpret: bool = True,
+    use_pallas: bool = False,
 ):
     """One :func:`prepare_env` entry: ``("closure:<super>", sorted id set)``.
 
@@ -1035,11 +1028,11 @@ def closure_env_entry(
 
     edges = subclass_edges(kb, subclass_pred)
     return "closure:%d" % super_class, jnp.asarray(
-        _closure_set(edges, super_class, use_pallas, interpret))
+        _closure_set(edges, super_class, use_pallas))
 
 
 def _closure_set(
-    edges, root: int, use_pallas: bool, interpret: bool
+    edges, root: int, use_pallas: bool
 ) -> np.ndarray:
     if use_pallas and edges:
         idx, ids = build_class_index(edges)
@@ -1048,8 +1041,7 @@ def _closure_set(
 
             adj = adjacency_from_edges(edges, idx)
             dids, count = cl_ops.closure_descendants(
-                np.asarray(adj), idx[root], out_cap=len(ids),
-                interpret=interpret)
+                np.asarray(adj), idx[root], out_cap=len(ids))
             sel = np.asarray(dids)[: int(count)]
             return np.sort(ids[sel]).astype(np.uint32)
         # no subclass edge touches the root: closure is just {root}

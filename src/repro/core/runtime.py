@@ -85,11 +85,6 @@ class RuntimeConfig:
     # per join from the actual (bind_cap, used-KB capacity, num_vars) via
     # kernels.hash_join.ops.autotune_block_shapes at trace time.
     join_block_shapes: Optional[Tuple[int, int]] = None
-    # Pallas interpret mode for the fused join/closure kernels: True runs
-    # the kernels through the interpreter (works on CPU hosts), False
-    # compiles them for the real accelerator.  Only consulted when
-    # ``use_pallas`` selects a Pallas path.
-    interpret: bool = True
 
 
 # --------------------------------------------------------------------------
@@ -151,8 +146,7 @@ def build_operators(
         if sub.touches_kb:
             op_kb = prune_kb_for(sub.query, kb)
             op_kb = augment_kb_with_closures(
-                sub.query, op_kb, use_pallas=config.use_pallas,
-                interpret=config.interpret)
+                sub.query, op_kb, use_pallas=config.use_pallas)
             if config.kb_method == "auto":
                 kb_stats = collect_kb_stats(op_kb)
             if config.kb_capacity:
@@ -167,11 +161,9 @@ def build_operators(
             use_pallas=config.use_pallas,
             fuse_compaction=config.fuse_compaction,
             join_bm=join_bm, join_bn=join_bn,
-            interpret=config.interpret,
             kb_stats=kb_stats,
         )
-        env = prepare_env(sub.query, kb, use_pallas=config.use_pallas,
-                          interpret=config.interpret)
+        env = prepare_env(sub.query, kb, use_pallas=config.use_pallas)
         operators[name] = SCEPOperator(name, plan, op_kb, env, op_cfg)
     return operators
 
@@ -518,21 +510,18 @@ class MonolithicRuntime:
         config = config if config is not None else RuntimeConfig()
         join_bm, join_bn = config.join_block_shapes or (None, None)
         # closure-pair relations for variable-length paths (no-op otherwise)
-        kb = augment_kb_with_closures(q, kb, use_pallas=config.use_pallas,
-                                      interpret=config.interpret)
+        kb = augment_kb_with_closures(q, kb, use_pallas=config.use_pallas)
         plan = compile_query(
             q, kb_method=config.kb_method, scan_cap=config.scan_cap,
             bind_cap=config.bind_cap, out_cap=config.out_cap,
             use_pallas=config.use_pallas,
             fuse_compaction=config.fuse_compaction,
             join_bm=join_bm, join_bn=join_bn,
-            interpret=config.interpret,
             kb_stats=(collect_kb_stats(kb)
                       if config.kb_method == "auto" and kb is not None
                       else None),
         )
-        env = prepare_env(q, kb, use_pallas=config.use_pallas,
-                          interpret=config.interpret)
+        env = prepare_env(q, kb, use_pallas=config.use_pallas)
         if config.kb_capacity:
             kb = pad_to(kb, config.kb_capacity)
         self.operator = SCEPOperator(
@@ -589,8 +578,13 @@ def shard_windows(windows: Windows, mesh: Mesh, axis: str = "data") -> Windows:
     """Constrain a window batch to live across a mesh axis (jit-side).
 
     Each device gets a window slice and runs the identical engine program —
-    the SPMD version of the paper's consumer-group load balancing.
+    the SPMD version of the paper's consumer-group load balancing.  Any
+    mesh works: ``jax.make_mesh`` builds Explicit axes, which a sharding
+    constraint may not name, so the constraint uses an Auto-typed view of
+    the same devices.
     """
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
     return jax.tree.map(
         lambda leaf: jax.lax.with_sharding_constraint(
             leaf, NamedSharding(mesh, P(*((axis,) + (None,) * (leaf.ndim - 1)))),

@@ -16,7 +16,7 @@ device channels).  ``Session`` collapses that into one code path::
 A single frozen :class:`ExecutionConfig` consolidates every knob that was
 spread over ``RuntimeConfig``, ``OperatorConfig`` and per-runtime constructor
 arguments: window geometry, engine capacities, KB-access method, Pallas
-selection (``use_pallas`` / ``fuse_compaction`` / ``interpret``), the mesh
+selection (``use_pallas`` / ``fuse_compaction``), the mesh
 for SPMD window sharding (``single_program`` mode), and operator placement +
 channel depth (``pipelined`` mode).
 
@@ -82,12 +82,11 @@ class ExecutionConfig:
     bind_cap: int = 256
     out_cap: int = 512
     intermediate_cap: int = 512
+    # Pallas kernels run through the interpreter exactly when the backend
+    # is the CPU (repro.kernels.resolve_interpret); there is no knob for it
     use_pallas: bool = False
     fuse_compaction: bool = False
     join_block_shapes: Optional[Tuple[int, int]] = None
-    # Pallas interpret mode: True = interpreter (CPU hosts), False = compile
-    # the fused kernels for the real accelerator
-    interpret: bool = True
 
     # -- execution mode and distribution ------------------------------------
     mode: str = "single_program"       # monolithic | single_program | pipelined
@@ -167,7 +166,6 @@ class ExecutionConfig:
             use_pallas=self.use_pallas,
             fuse_compaction=self.fuse_compaction,
             join_block_shapes=self.join_block_shapes,
-            interpret=self.interpret,
         )
 
     def replace(self, **changes) -> "ExecutionConfig":

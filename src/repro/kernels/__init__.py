@@ -3,7 +3,7 @@
 Each kernel package ships three files:
 
 * ``kernel.py`` — ``pl.pallas_call`` + explicit ``BlockSpec`` VMEM tiling
-  (TPU is the target; ``interpret=True`` validates on CPU),
+  (TPU is the target; the Pallas interpreter runs them on CPU),
 * ``ops.py``    — the jit'd public wrapper (padding, dtype policy, vmap),
 * ``ref.py``    — the pure-jnp oracle every test sweeps against.
 
@@ -15,4 +15,20 @@ Kernels:
   subclass reasoning on the MXU),
 * ``flash_attention`` — GQA flash attention fwd (causal / sliding-window),
 * ``ssd``             — Mamba-2 state-space-duality chunked scan.
+
+Interpret mode is a property of the platform, not a user option: the
+kernels run through the Pallas interpreter exactly when JAX's default
+backend is the CPU, and are compiled by Mosaic everywhere else.
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``interpret`` if given, else the platform rule: interpret on CPU."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"

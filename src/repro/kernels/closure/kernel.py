@@ -21,6 +21,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
+from repro.kernels.walk import walk
 
 
 def _bool_matmul_kernel(nk: int, a_ref, b_ref, out_ref):
@@ -39,32 +43,46 @@ def _bool_matmul_kernel(nk: int, a_ref, b_ref, out_ref):
         out_ref[...] = jnp.minimum(out_ref[...], 1.0)
 
 
-def _descendants_kernel(out_cap: int, reach_ref, rootcol_ref, ids_ref,
-                        count_ref):
+def _descendants_kernel(out_cap: int, reach_ref, root_ref, ids_ref,
+                        count_ref, flat_ref):
     """Fused final squaring + compaction for one root class.
 
     One ``[bm, n]`` row block per grid step: the block's slice of the final
-    closure *column* is a matvec ``reach_block @ reach[:, root]`` (the full
-    ``reach @ reach`` product for the last squaring never exists), and set
-    rows scatter their global indices straight into the capacity-bounded id
-    list.  ``count_ref`` carries the running count across the sequential
-    grid; slot ``out_cap`` of ``ids_ref`` is the dump slot for overflow.
+    closure *column* is ``reach_block @ reach[:, root]`` (the full
+    ``reach @ reach`` product for the last squaring never exists), taken as
+    a lane reduction of the block against the root column laid out as a
+    ``[1, n]`` row.  Set rows append their global indices to the SMEM id
+    list in ascending order (:mod:`repro.kernels.walk`);
+    ``count_ref`` carries the full running count across the sequential grid.
     """
     i = pl.program_id(0)
     bm = reach_ref.shape[0]
 
     @pl.when(i == 0)
     def _init():
-        ids_ref[...] = jnp.zeros_like(ids_ref)
+        count_ref[0] = 0
 
-    col = jnp.minimum(reach_ref[...] @ rootcol_ref[...], 1.0)     # [bm]
-    mask = col > 0.5
-    base = jnp.where(i == 0, 0, count_ref[0])
-    rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
-    tgt = jnp.where(mask & (base + rank < out_cap), base + rank, out_cap)
-    ids = (i * bm + jnp.arange(bm)).astype(jnp.int32)
-    ids_ref[...] = ids_ref[...].at[tgt].set(ids)
-    count_ref[0] = base + jnp.sum(mask.astype(jnp.int32))
+        def zero(t, carry):
+            ids_ref[t] = 0
+            return carry
+
+        jax.lax.fori_loop(0, out_cap + 1, zero, 0)
+
+    col = jnp.sum(reach_ref[...] * root_ref[...], axis=1, keepdims=True)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+    hit = col > 0.5                                              # [bm, 1]
+    base = count_ref[0]
+
+    @pl.when(base < out_cap)
+    def _():
+        flat_ref[...] = jnp.where(hit, row, bm)
+
+        def write(pos, nxt):
+            ids_ref[pos] = i * bm + nxt
+
+        walk(flat_ref, bm, out_cap, lambda nxt, t: base + t, write)
+
+    count_ref[0] = base + jnp.sum(hit.astype(jnp.int32))
 
 
 def descendants_pallas(
@@ -72,40 +90,41 @@ def descendants_pallas(
     rootcol: jax.Array,     # [n] f32: reach[:, root]
     out_cap: int,
     bm: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns ``(ids [out_cap] int32, count [] int32)``.
 
     ``ids[:min(count, out_cap)]`` are the ascending row indices i with
     ``min(reach @ reach, 1)[i, root] > 0.5`` — the root's descendant set,
-    compacted in-kernel without materializing the final squared matrix.
+    compacted in-kernel without materializing the final squared matrix;
+    later entries are zero.
     """
     n = reach.shape[0]
     assert reach.shape == (n, n) and n % bm == 0, (reach.shape, bm)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     ids, count = pl.pallas_call(
         functools.partial(_descendants_kernel, out_cap),
         grid=(n // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec((1, n), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((out_cap + 1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
+        out_specs=[smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((out_cap + 1,), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
-        interpret=interpret,
-    )(reach, rootcol)
+        scratch_shapes=[pltpu.VMEM((bm, 1), jnp.int32)],
+        interpret=resolve_interpret(interpret),
+        name="closure_descendants",
+    )(reach, rootcol.reshape(1, n))
     return ids[:out_cap], count[0]
 
 
 def closure_step_pallas(
     reach: jax.Array,           # [n, n] f32 in {0, 1}, n multiple of block
     bm: int = 128, bn: int = 128, bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     n = reach.shape[0]
     assert reach.shape == (n, n) and n % bm == 0 and n % bn == 0 and n % bk == 0
@@ -120,5 +139,6 @@ def closure_step_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="closure_step",
     )(reach, reach)

@@ -19,7 +19,7 @@ def _pad_square(x: jax.Array, mult: int) -> jax.Array:
 
 def transitive_closure(
     adj: jax.Array, max_depth: int | None = None, block: int = 128,
-    use_pallas: bool = True, interpret: bool = True,
+    use_pallas: bool = True, interpret: bool | None = None,
 ) -> jax.Array:
     """Reflexive-transitive closure of ``adj`` (bool/float in {0,1}).
 
@@ -42,7 +42,7 @@ def transitive_closure(
 
 def closure_descendants(
     adj: jax.Array, root: int, out_cap: int, max_depth: int | None = None,
-    block: int = 128, use_pallas: bool = True, interpret: bool = True,
+    block: int = 128, use_pallas: bool = True, interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Descendant set of class ``root``: fused closure + compaction.
 
@@ -73,7 +73,7 @@ def closure_descendants(
 
 def closure_ancestors(
     adj: jax.Array, root: int, out_cap: int, max_depth: int | None = None,
-    block: int = 128, use_pallas: bool = True, interpret: bool = True,
+    block: int = 128, use_pallas: bool = True, interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Ancestor set of node ``root``: everything ``root`` reaches.
 

@@ -3,48 +3,38 @@
 TPU adaptation of DSCEP's KB-scan join.  A CPU engine (C-SPARQL) walks hash
 maps pointer-by-pointer; the TPU-native formulation streams the KB partition
 through VMEM in ``bn``-wide blocks and evaluates all ``bm x bn`` slot-equality
-predicates as vector compares (VPU).  Two entry points:
+predicates as vector compares (VPU).  Three entry points:
 
 * :func:`match_matrix_pallas` — the original kernel: emits the full int8
   candidate matrix ``[M, N]`` that the caller compacts.  O(M*N) HBM traffic.
 * :func:`join_compact_pallas` — the fused pipeline: match tiles never leave
-  VMEM; each grid tile scatters its compacted, variable-extended binding rows
-  straight into a capacity-bounded ``[out_cap, nv]`` output.  HBM traffic is
-  O(M*N / tile-resident) reads + O(out_cap) writes, and the output positions
-  are *globally row-major deterministic* — bit-identical to materializing the
+  VMEM; the kernel emits, for each of the first ``out_cap`` matches in
+  global row-major order, its ``(binding row, KB row)`` source pair.  The
+  caller gathers those ``out_cap`` rows — bit-identical to materializing the
   candidate matrix and running :func:`repro.core.pattern.compact_rows`.
-* :func:`probe_compact_pallas` — the probe-method analogue: per binding row
-  a binary search over the resident sorted composite-key view, a bounded
-  ``k_max``-wide gather, the exact anchor re-check, and the same
-  scatter-compaction — all in one kernel pass whose cost is independent of
-  unused-KB size (the planner's ``kb_method="auto"`` picks this whenever
-  the pattern is anchored and the observed fan-out is small).
+* :func:`probe_compact_pallas` — the probe-method analogue: over the
+  ``[M, k_max]`` candidates a sorted-index probe gathered, re-check every
+  slot exactly and emit the flat indices of the first ``out_cap`` matches.
 
-The fused pipeline is classic two-phase stream compaction:
+The fused scan is classic two-phase stream compaction:
 
 1. **count** — grid ``(M/bm, N/bn)`` accumulates per-binding-row match
-   counts into an ``[M]`` int32 vector (the only intermediate that touches
-   HBM; 4 bytes/row vs N bytes/row for the candidate matrix).
-2. host-side exclusive cumsum of the ``[M]`` counts -> global row offsets.
-3. **scatter** — same grid; each tile recomputes its match block (compare
-   ops are ~free; recompute beats an HBM round-trip), ranks matches within
-   the row via a running per-row base carried across ``j`` steps, extends
-   binding rows with the pattern's FREE variables from the KB columns, and
-   scatters them to ``offset[row] + rank``.  Rows past ``out_cap`` land in a
-   dump slot; the caller turns ``sum(counts) > out_cap`` into the overflow
-   flag.
+   counts into an ``[M, 1]`` int32 vector (4 bytes/row vs N bytes/row for
+   the candidate matrix).
+2. exclusive cumsum of the counts in XLA -> global row offsets.
+3. **emit** — same grid; each tile recomputes its match block (compares are
+   ~free; recompute beats an HBM round-trip) and walks its matches in
+   row-major order.  A match of binding row ``r`` lands at
+   ``offset[r] + (matches of r already emitted)``.
 
-Grids iterate ``j`` fastest (Pallas row-major order), which the running
-per-row base in phase 3 relies on; the scatter itself is position-exact, so
-tile order never changes the result.
-
-Lowering note: the scatter step uses a runtime-indexed ``.at[].set`` into
-the resident output block.  This is exercised in interpret mode (this
-container) and is the one op whose Mosaic lowering must be validated before
-flipping ``interpret=False`` on real hardware; if unsupported on a target
-TPU generation, replace it with a one-hot-matmul scatter (MXU) or a
-per-row ``fori_loop`` of dynamic-slice stores — the count/offset phases and
-the output contract are unchanged.
+How the kernels stay inside what Mosaic lowers: every id travels as int32
+(a bit-preserving view of the uint32 ids), validity is an int32 0/1 column
+``[M, 1]`` or row ``[1, N]`` so no bool vector is ever reshaped, and the
+compaction is a walk rather than a cumsum plus a scatter.  Each step of the
+walk takes the smallest flat index above the last one (one vector min over
+the tile) and stores a scalar into an SMEM output.  Positions grow along the
+walk, so it stops at the first match past ``out_cap``: a call does at most
+``out_cap`` steps plus one per tile.
 """
 from __future__ import annotations
 
@@ -53,25 +43,48 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pattern import CompiledPattern, SlotMode
+from repro.kernels import resolve_interpret
+from repro.kernels.walk import flat_index, lane_shift, walk
 
 DEFAULT_BM = 128
 DEFAULT_BN = 1024
 
 
+def as_i32(x: jax.Array) -> jax.Array:
+    """uint32 ids keep their bits; bool masks become 0/1."""
+    if x.dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def _i32_const(term_id: int) -> int:
+    """A uint32 term id as the int32 with the same bits."""
+    return int(np.array(term_id, np.uint32).view(np.int32))
+
+
+def _eq(a, b) -> jax.Array:
+    return (a == b).astype(jnp.int32)
+
+
 def _tile_match(pat: CompiledPattern, cols, bvalid, ks, kp, ko, kvalid):
-    """All-slot equality for one [bm, bn] tile under the static pattern."""
-    kcols = {0: ks, 1: kp, 2: ko}
-    m = bvalid[:, None] & kvalid[None, :]
-    for i, slot in enumerate((pat.s, pat.p, pat.o)):
-        kv = kcols[i][None, :]
-        if slot.mode == SlotMode.CONST:
-            m = m & (kv == jnp.uint32(slot.const))
-        elif slot.mode == SlotMode.BOUND:
-            m = m & (kv == cols[:, slot.var][:, None])
+    """All-slot equality for one tile, as int32 0/1 ``[bm, bn]``.
+
+    ``cols [bm, nv]`` and ``bvalid [bm, 1]`` are the binding side,
+    ``ks/kp/ko/kvalid [1, bn]`` the KB block; all int32.
+    """
+    kcols = (ks, kp, ko)
     slots = (pat.s, pat.p, pat.o)
+    m = bvalid & kvalid
+    for i, slot in enumerate(slots):
+        if slot.mode == SlotMode.CONST:
+            m = m & _eq(kcols[i], _i32_const(slot.const))
+        elif slot.mode == SlotMode.BOUND:
+            m = m & _eq(kcols[i], cols[:, slot.var:slot.var + 1])
     for i in range(3):
         for j in range(i + 1, 3):
             if (
@@ -79,22 +92,26 @@ def _tile_match(pat: CompiledPattern, cols, bvalid, ks, kp, ko, kvalid):
                 and slots[j].mode != SlotMode.CONST
                 and slots[i].var == slots[j].var
             ):
-                m = m & (kcols[i][None, :] == kcols[j][None, :])
+                m = m & _eq(kcols[i], kcols[j])
     return m
 
 
-def _extend_tile(pat: CompiledPattern, cols, ks, kp, ko):
-    """[bm, nv] binding rows -> [bm, bn, nv] rows with FREE vars from the KB."""
-    bm, nv = cols.shape
-    bn = ks.shape[0]
-    ext = jnp.broadcast_to(cols[:, None, :], (bm, bn, nv))
-    kcols = {0: ks, 1: kp, 2: ko}
+def _probe_match(pat: CompiledPattern, cols, bvalid, ms, mp, mo, ok):
+    """Anchor/const re-check on gathered ``[bm, k]`` candidates (int32 0/1).
+
+    Exact parity with :func:`repro.core.algebra.kb_join_probe`'s
+    verification loop: the composite probe key hashes numeric literals, so
+    anchors must be re-checked with true equality, and the non-anchored
+    endpoint is verified here too.  Every input is int32.
+    """
+    m = ok & bvalid
+    kcols = (ms, mp, mo)
     for i, slot in enumerate((pat.s, pat.p, pat.o)):
-        if slot.mode == SlotMode.FREE:
-            ext = ext.at[..., slot.var].set(
-                jnp.broadcast_to(kcols[i][None, :], (bm, bn))
-            )
-    return ext
+        if slot.mode == SlotMode.CONST:
+            m = m & _eq(kcols[i], _i32_const(slot.const))
+        elif slot.mode == SlotMode.BOUND:
+            m = m & _eq(kcols[i], cols[:, slot.var:slot.var + 1])
+    return m
 
 
 # --------------------------------------------------------------------------
@@ -109,35 +126,38 @@ def _match_kernel(pat: CompiledPattern, cols_ref, bvalid_ref, ks_ref, kp_ref,
     out_ref[...] = m.astype(jnp.int8)
 
 
+def _scan_specs(bm: int, bn: int, nv: int):
+    return [
+        pl.BlockSpec((bm, nv), lambda i, j: (i, 0)),    # binding tile
+        pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),     # binding validity
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),     # KB subject block
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),     # KB predicate block
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),     # KB object block
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),     # KB validity block
+    ]
+
+
 def match_matrix_pallas(
-    cols: jax.Array,        # [M, NV] uint32 (M multiple of bm)
-    bvalid: jax.Array,      # [M] bool
-    ks: jax.Array, kp: jax.Array, ko: jax.Array,   # [N] uint32 (N mult of bn)
-    kvalid: jax.Array,      # [N] bool
+    cols: jax.Array,        # [M, NV] int32 (M multiple of bm)
+    bvalid: jax.Array,      # [M, 1] int32 0/1
+    ks: jax.Array, kp: jax.Array, ko: jax.Array,   # [1, N] int32 (N mult of bn)
+    kvalid: jax.Array,      # [1, N] int32 0/1
     pat: CompiledPattern,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,  # CPU container: interpret; flip off on real TPU
+    interpret: bool | None = None,
 ) -> jax.Array:
     m, nv = cols.shape
-    n = ks.shape[0]
+    n = ks.shape[1]
     assert m % bm == 0 and n % bn == 0, (m, bm, n, bn)
-    grid = (m // bm, n // bn)
-    kern = functools.partial(_match_kernel, pat)
     return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, nv), lambda i, j: (i, 0)),    # binding tile
-            pl.BlockSpec((bm,), lambda i, j: (i,)),         # binding validity
-            pl.BlockSpec((bn,), lambda i, j: (j,)),         # KB subject block
-            pl.BlockSpec((bn,), lambda i, j: (j,)),         # KB predicate block
-            pl.BlockSpec((bn,), lambda i, j: (j,)),         # KB object block
-            pl.BlockSpec((bn,), lambda i, j: (j,)),         # KB validity block
-        ],
+        functools.partial(_match_kernel, pat),
+        grid=(m // bm, n // bn),
+        in_specs=_scan_specs(bm, bn, nv),
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="hash_join_match",
     )(cols, bvalid, ks, kp, ko, kvalid)
 
 
@@ -148,247 +168,192 @@ def match_matrix_pallas(
 def _count_kernel(pat: CompiledPattern, cols_ref, bvalid_ref, ks_ref, kp_ref,
                   ko_ref, kvalid_ref, counts_ref):
     """Phase 1: accumulate per-binding-row match counts across KB blocks."""
-    j = pl.program_id(1)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        counts_ref[...] = jnp.zeros_like(counts_ref)
+
     m = _tile_match(pat, cols_ref[...], bvalid_ref[...], ks_ref[...],
                     kp_ref[...], ko_ref[...], kvalid_ref[...])
-    rc = jnp.sum(m.astype(jnp.int32), axis=1)
-    counts_ref[...] = jnp.where(j == 0, jnp.zeros_like(rc),
-                                counts_ref[...]) + rc
+    counts_ref[...] += jnp.sum(m, axis=1, keepdims=True)
 
 
-def _scatter_kernel(pat: CompiledPattern, out_cap: int, cols_ref, bvalid_ref,
-                    ks_ref, kp_ref, ko_ref, kvalid_ref, offs_ref, out_ref,
-                    rowbase_ref):
-    """Phase 2: scatter compacted extended rows to offset[row] + rank.
+def _emit_kernel(pat: CompiledPattern, out_cap: int, cols_ref, bvalid_ref,
+                 ks_ref, kp_ref, ko_ref, kvalid_ref, offs_ref, bi_ref, kr_ref,
+                 flat_ref, base_ref):
+    """Phase 3: emit the source pair of every match placed before out_cap.
 
-    ``out_ref`` is the whole ``[out_cap + 1, nv]`` output (constant index
-    map — the TPU grid is sequential, so revisiting accumulates); row
-    ``out_cap`` is the dump slot for overflowing matches.  ``rowbase_ref``
-    carries each binding row's running match count across ``j`` steps.
+    ``bi_ref``/``kr_ref`` are whole ``[1, out_cap + 1]`` SMEM outputs (constant
+    index map — the TPU grid is sequential, so every tile writes into the
+    same resident buffer).  ``base_ref`` counts each binding row's emitted
+    matches across ``j`` steps.  A row's matches stop being emitted only at
+    or past ``out_cap``, where its later matches (and every later row's)
+    also land, so the count is exact wherever it is read.
     """
     i, j = pl.program_id(0), pl.program_id(1)
+    bm, bn = flat_ref.shape
+    shift = lane_shift(bn)
 
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    cols = cols_ref[...]
-    ks, kp, ko = ks_ref[...], kp_ref[...], ko_ref[...]
-    m = _tile_match(pat, cols, bvalid_ref[...], ks, kp, ko, kvalid_ref[...])
-    rc = jnp.sum(m.astype(jnp.int32), axis=1)                     # [bm]
-    base = jnp.where(j == 0, jnp.zeros_like(rc), rowbase_ref[...])
-    rank = jnp.cumsum(m.astype(jnp.int32), axis=1) - 1            # [bm, bn]
-    tgt = offs_ref[...][:, None] + base[:, None] + rank
-    tgt = jnp.where(m & (tgt < out_cap), tgt, out_cap)            # dump slot
-
-    ext = _extend_tile(pat, cols, ks, kp, ko)                     # [bm, bn, nv]
-    bm, bn, nv = ext.shape
-    out_ref[...] = out_ref[...].at[tgt.reshape(bm * bn)].set(
-        ext.reshape(bm * bn, nv)
-    )
-    rowbase_ref[...] = base + rc
-
-
-# --------------------------------------------------------------------------
-# fused probe kernel: searchsorted + bounded gather + re-check + compaction
-# --------------------------------------------------------------------------
-
-def _probe_match(pat: CompiledPattern, cols, bvalid, ms, mp, mo, ok):
-    """Anchor/const re-check on gathered ``[bm, k]`` candidate rows.
-
-    Exact parity with :func:`repro.core.algebra.kb_join_probe`'s
-    verification loop: the composite probe key hashes numeric literals, so
-    anchors must be re-checked with true equality, and the non-anchored
-    endpoint is verified here too.
-    """
-    m = ok & bvalid[:, None]
-    kcols = {0: ms, 1: mp, 2: mo}
-    for i, slot in enumerate((pat.s, pat.p, pat.o)):
-        if slot.mode == SlotMode.CONST:
-            m = m & (kcols[i] == jnp.uint32(slot.const))
-        elif slot.mode == SlotMode.BOUND:
-            m = m & (kcols[i] == cols[:, slot.var][:, None])
-    return m
-
-
-def _probe_extend(pat: CompiledPattern, cols, ms, mp, mo):
-    """[bm, nv] binding rows -> [bm, k, nv] rows with FREE vars gathered."""
-    bm, nv = cols.shape
-    k = ms.shape[1]
-    ext = jnp.broadcast_to(cols[:, None, :], (bm, k, nv))
-    kcols = {0: ms, 1: mp, 2: mo}
-    for i, slot in enumerate((pat.s, pat.p, pat.o)):
-        if slot.mode == SlotMode.FREE:
-            ext = ext.at[..., slot.var].set(kcols[i])
-    return ext
-
-
-def _probe_kernel(pat: CompiledPattern, anchor_is_s: bool, k_max: int,
-                  out_cap: int, cols_ref, bvalid_ref, ks_ref, kp_ref, ko_ref,
-                  keys_ref, out_ref, counts_ref, fan_ref, base_ref):
-    """One ``[bm]`` binding tile: probe, gather, re-check, scatter-compact.
-
-    The grid is 1-D over binding tiles and TPU grids run sequentially, so
-    ``base_ref`` (a ``[1]`` output revisited by every tile) carries the
-    global running match count — output positions are globally row-major
-    over the virtual ``[M, k_max]`` candidate block, bit-identical to
-    compacting the unfused probe's extension.  Row ``out_cap`` of the
-    resident output is the dump slot for overflowing matches.
-
-    Lowering note: like the scan-path scatter, this kernel leans on
-    runtime-indexed ``.at[].set`` plus ``jnp.searchsorted``/``jnp.take``
-    gathers; all are exercised in interpret mode here and must be validated
-    under Mosaic before flipping ``interpret=False`` on real hardware.
-    """
-    from repro.core.rdf import composite_key
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(j == 0)
+    def _():
         base_ref[...] = jnp.zeros_like(base_ref)
 
-    cols = cols_ref[...]
-    keys = keys_ref[...]
-    bm = cols.shape[0]
-    anchor = pat.s if anchor_is_s else pat.o
-    if anchor.mode == SlotMode.CONST:
-        aval = jnp.full((bm,), jnp.uint32(anchor.const))
-    else:
-        aval = cols[:, anchor.var]
-    qk = composite_key(jnp.uint32(pat.p.const), aval)
-    lo = jnp.searchsorted(keys, qk, side="left")
-    hi = jnp.searchsorted(keys, qk, side="right")
-    idx = lo[:, None] + jnp.arange(k_max, dtype=lo.dtype)
-    ok = idx < hi[:, None]
-    idx_safe = jnp.minimum(idx, keys.shape[0] - 1)
-    ms = jnp.take(ks_ref[...], idx_safe, axis=0)
-    mp = jnp.take(kp_ref[...], idx_safe, axis=0)
-    mo = jnp.take(ko_ref[...], idx_safe, axis=0)
-    m = _probe_match(pat, cols, bvalid_ref[...], ms, mp, mo, ok)
+    offs = offs_ref[...]                                    # [bm, 1]
 
-    rc = jnp.sum(m.astype(jnp.int32), axis=1)                     # [bm]
-    ext = _probe_extend(pat, cols, ms, mp, mo)                    # [bm, k, nv]
-    flat_m = m.reshape(bm * k_max)
-    rank = jnp.cumsum(flat_m.astype(jnp.int32)) - 1
-    base = base_ref[0]
-    tgt = base + rank
-    tgt = jnp.where(flat_m & (tgt < out_cap), tgt, out_cap)       # dump slot
-    nv = cols.shape[1]
-    out_ref[...] = out_ref[...].at[tgt].set(ext.reshape(bm * k_max, nv))
-    counts_ref[...] = rc
-    fan_ref[...] = ((hi - lo) > k_max).astype(jnp.int32)
-    base_ref[0] = base + jnp.sum(rc)
+    @pl.when(jnp.min(offs) < out_cap)
+    def _():
+        m = _tile_match(pat, cols_ref[...], bvalid_ref[...], ks_ref[...],
+                        kp_ref[...], ko_ref[...], kvalid_ref[...])
+        big = bm << shift
+        flat_ref[...] = jnp.where(m != 0, flat_index((bm, bn), shift), big)
+        row = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
 
+        def place(nxt, t):
+            return jnp.sum(jnp.where(row == (nxt >> shift),
+                                     offs + base_ref[...], 0))
 
-def probe_compact_pallas(
-    cols: jax.Array,        # [M, NV] uint32 (M multiple of bm)
-    bvalid: jax.Array,      # [M] bool
-    ks: jax.Array, kp: jax.Array, ko: jax.Array,   # [N] view columns
-    keys: jax.Array,        # [N] uint32 sorted composite keys (pads = max)
-    pat: CompiledPattern,
-    anchor_is_s: bool,
-    out_cap: int,
-    k_max: int = 8,
-    bm: int = DEFAULT_BM,
-    interpret: bool = True,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused probe join.  Returns ``(rows [out_cap, nv], counts [M],
-    fan_overflow [M])``.
+        def write(pos, nxt):
+            r = nxt >> shift
+            bi_ref[0, pos] = i * bm + r
+            kr_ref[0, pos] = j * bn + (nxt & ((1 << shift) - 1))
+            base_ref[...] += (row == r).astype(jnp.int32)
 
-    ``rows[k]`` is the k-th match of the virtual row-major ``[M, k_max]``
-    candidate block, extended with the pattern's FREE variables;
-    ``fan_overflow[r]`` flags probe ranges wider than ``k_max`` (clipped
-    gathers).  The sorted view stays resident in VMEM (one block), so each
-    tile pays O(bm log N) compares + O(bm * k_max) gathers — no O(N) scan.
-    """
-    m, nv = cols.shape
-    n = ks.shape[0]
-    assert m % bm == 0, (m, bm)
-    grid = (m // bm,)
-    kern = functools.partial(_probe_kernel, pat, anchor_is_s, k_max, out_cap)
-    out, counts, fan, _ = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, nv), lambda i: (i, 0)),   # binding tile
-            pl.BlockSpec((bm,), lambda i: (i,)),        # binding validity
-            pl.BlockSpec((n,), lambda i: (0,)),         # view subjects
-            pl.BlockSpec((n,), lambda i: (0,)),         # view predicates
-            pl.BlockSpec((n,), lambda i: (0,)),         # view objects
-            pl.BlockSpec((n,), lambda i: (0,)),         # sorted keys
-        ],
-        out_specs=[
-            pl.BlockSpec((out_cap + 1, nv), lambda i: (0, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),         # running base
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((out_cap + 1, nv), jnp.uint32),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(cols, bvalid, ks, kp, ko, keys)
-    return out[:out_cap], counts, fan
+        walk(flat_ref, big, out_cap, place, write)
 
 
 def join_compact_pallas(
-    cols: jax.Array,        # [M, NV] uint32 (M multiple of bm)
-    bvalid: jax.Array,      # [M] bool
-    ks: jax.Array, kp: jax.Array, ko: jax.Array,   # [N] uint32 (N mult of bn)
-    kvalid: jax.Array,      # [N] bool
+    cols: jax.Array,        # [M, NV] int32 (M multiple of bm)
+    bvalid: jax.Array,      # [M, 1] int32 0/1
+    ks: jax.Array, kp: jax.Array, ko: jax.Array,   # [1, N] int32 (N mult of bn)
+    kvalid: jax.Array,      # [1, N] int32 0/1
     pat: CompiledPattern,
     out_cap: int,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
-) -> Tuple[jax.Array, jax.Array]:
-    """Fused join+compaction.  Returns ``(rows [out_cap, nv], counts [M])``.
+    interpret: bool | None = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused join+compaction.
 
-    ``rows[k]`` is the k-th match of the (virtual) row-major candidate
-    matrix, extended with the pattern's FREE variables; slots past the total
-    match count hold garbage (callers mask with ``sum(counts)``).  The
-    candidate matrix itself never exists in HBM.
+    Returns ``(bind_row [out_cap], kb_row [out_cap], counts [M, 1])``:
+    entry ``k`` names the binding row and KB row of the k-th match of the
+    (virtual) row-major candidate matrix.  Entries past the total match count
+    are unspecified (callers mask with ``sum(counts)``).  The candidate
+    matrix itself never exists in HBM.
     """
     m, nv = cols.shape
-    n = ks.shape[0]
+    n = ks.shape[1]
     assert m % bm == 0 and n % bn == 0, (m, bm, n, bn)
+    interpret = resolve_interpret(interpret)
     grid = (m // bm, n // bn)
-    in_specs = [
-        pl.BlockSpec((bm, nv), lambda i, j: (i, 0)),
-        pl.BlockSpec((bm,), lambda i, j: (i,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
-    ]
+    specs = _scan_specs(bm, bn, nv)
     counts = pl.pallas_call(
         functools.partial(_count_kernel, pat),
         grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
         interpret=interpret,
+        name="hash_join_count",
     )(cols, bvalid, ks, kp, ko, kvalid)
 
-    offsets = (jnp.cumsum(counts) - counts).astype(jnp.int32)   # [M], tiny
+    offsets = jnp.cumsum(counts, axis=0) - counts           # [M, 1], tiny
 
-    out, _ = pl.pallas_call(
-        functools.partial(_scatter_kernel, pat, out_cap),
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    bi, kr = pl.pallas_call(
+        functools.partial(_emit_kernel, pat, out_cap),
         grid=grid,
-        in_specs=in_specs + [pl.BlockSpec((bm,), lambda i, j: (i,))],
+        in_specs=specs + [pl.BlockSpec((bm, 1), lambda i, j: (i, 0))],
+        out_specs=[smem, smem],
+        out_shape=[jax.ShapeDtypeStruct((1, out_cap + 1), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
+                        pltpu.VMEM((bm, 1), jnp.int32)],
+        interpret=interpret,
+        name="hash_join_emit",
+    )(cols, bvalid, ks, kp, ko, kvalid, offsets)
+    return bi[0, :out_cap], kr[0, :out_cap], counts
+
+
+# --------------------------------------------------------------------------
+# fused probe kernel: exact re-check + compaction of gathered candidates
+# --------------------------------------------------------------------------
+
+def _probe_kernel(pat: CompiledPattern, out_cap: int, cols_ref, bvalid_ref,
+                  ms_ref, mp_ref, mo_ref, ok_ref, src_ref, counts_ref,
+                  flat_ref, base_ref):
+    """One ``[bm]`` binding tile: re-check, count, emit.
+
+    The grid is 1-D over binding tiles and TPU grids run sequentially, so
+    ``base_ref`` (SMEM scratch) carries the global running match count —
+    output positions are globally row-major over the ``[M, k_max]``
+    candidate block, bit-identical to compacting the unfused probe's
+    extension.
+    """
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        base_ref[0] = 0
+
+    m = _probe_match(pat, cols_ref[...], bvalid_ref[...], ms_ref[...],
+                     mp_ref[...], mo_ref[...], ok_ref[...])
+    counts_ref[...] = jnp.sum(m, axis=1, keepdims=True)
+    bm, k = m.shape
+    shift = lane_shift(k)
+    base = base_ref[0]
+
+    @pl.when(base < out_cap)
+    def _():
+        big = bm << shift
+        flat_ref[...] = jnp.where(m != 0, flat_index((bm, k), shift), big)
+
+        def write(pos, nxt):
+            src_ref[0, pos] = (i * bm + (nxt >> shift)) * k + (
+                nxt & ((1 << shift) - 1))
+
+        walk(flat_ref, big, out_cap, lambda nxt, t: base + t, write)
+
+    base_ref[0] = base + jnp.sum(m)
+
+
+def probe_compact_pallas(
+    cols: jax.Array,        # [M, NV] int32 (M multiple of bm)
+    bvalid: jax.Array,      # [M, 1] int32 0/1
+    ms: jax.Array, mp: jax.Array, mo: jax.Array,   # [M, k] gathered candidates
+    ok: jax.Array,          # [M, k] int32 0/1: candidate inside its range
+    pat: CompiledPattern,
+    out_cap: int,
+    bm: int = DEFAULT_BM,
+    interpret: bool | None = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Fused probe re-check + compaction.
+
+    Returns ``(src [out_cap], counts [M, 1])``: ``src[t]`` is the flat
+    ``row * k + slot`` index of the t-th surviving candidate (row-major);
+    entries past the total are unspecified.  The range search and the
+    bounded gather that produce ``ms/mp/mo/ok`` run in XLA.
+    """
+    m, nv = cols.shape
+    k = ms.shape[1]
+    assert m % bm == 0, (m, bm)
+    row_tile = pl.BlockSpec((bm, k), lambda i: (i, 0))
+    src, counts = pl.pallas_call(
+        functools.partial(_probe_kernel, pat, out_cap),
+        grid=(m // bm,),
+        in_specs=[
+            pl.BlockSpec((bm, nv), lambda i: (i, 0)),   # binding tile
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),    # binding validity
+            row_tile, row_tile, row_tile, row_tile,
+        ],
         out_specs=[
-            pl.BlockSpec((out_cap + 1, nv), lambda i, j: (0, 0)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((out_cap + 1, nv), jnp.uint32),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct((1, out_cap + 1), jnp.int32),
+            jax.ShapeDtypeStruct((m, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(cols, bvalid, ks, kp, ko, kvalid, offsets)
-    return out[:out_cap], counts
+        scratch_shapes=[pltpu.VMEM((bm, k), jnp.int32),
+                        pltpu.SMEM((1,), jnp.int32)],
+        interpret=resolve_interpret(interpret),
+        name="hash_join_probe",
+    )(cols, bvalid, ms, mp, mo, ok)
+    return src[0, :out_cap], counts

@@ -19,8 +19,8 @@ devices by :func:`repro.launch.mesh.place_operators`, and an async
 software-pipelined schedule that keeps ``--channel-capacity`` chunks in
 flight (the host blocks only on the sink).  Reports sustained chunks/sec.
 
-``--no-interpret`` compiles the Pallas kernels for the real accelerator
-instead of the interpreter (requires actual TPU hardware).
+``--pallas`` runs the Pallas kernels: compiled by Mosaic on a TPU, through
+the Pallas interpreter on the CPU backend (the platform decides, not a flag).
 """
 from __future__ import annotations
 
@@ -38,6 +38,7 @@ from repro.data.dbpedia import KBConfig, generate_kb
 from repro.data.tweets import (
     TweetSchema, TweetStreamConfig, generate_tweets, stream_chunks,
 )
+from repro.launch.compile_cache import setup_compile_cache
 
 QUERIES = {"q15": PQ.Q15_RQ, "q16": PQ.Q16_RQ, "cquery1": PQ.CQUERY1_RQ}
 
@@ -71,9 +72,6 @@ def main(argv=None):
                     help="use the Pallas hash-join kernel")
     ap.add_argument("--fuse", action="store_true",
                     help="fused join->compaction (no [M, N] candidate matrix)")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compile Pallas kernels for real hardware instead "
-                         "of the interpreter (needs an actual TPU)")
     ap.add_argument("--channel-capacity", type=int, default=2,
                     help="slots per inter-operator channel = chunks kept "
                          "in flight (pipelined mode only)")
@@ -107,6 +105,7 @@ def main(argv=None):
                     help="chaos mode: operator-checkpoint cadence in "
                          "emitted chunks (0 disables checkpointing)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     if args.mode == "pipelined" and args.channel_capacity < 2:
         ap.error("--channel-capacity must be >= 2 (double buffering)")
     if args.chaos is not None and args.mode != "pipelined":
@@ -138,7 +137,6 @@ def main(argv=None):
         mode=args.mode, window_capacity=args.window_cap, max_windows=4,
         bind_cap=2048, scan_cap=512, out_cap=2048, kb_method=args.method,
         use_pallas=args.pallas, fuse_compaction=args.fuse,
-        interpret=not args.no_interpret,
         placement=args.placement, channel_capacity=args.channel_capacity,
         window_from_query=args.window_from_query,
         trace=args.trace,

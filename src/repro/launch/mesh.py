@@ -16,20 +16,29 @@ import os
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axes, so programs traced under the mesh
+    may constrain shardings (``with_sharding_constraint``)."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def ensure_host_devices(n: int = 4) -> None:
-    """Make the CPU backend expose ``n`` devices via
+    """On the CPU platform, make the backend expose ``n`` devices via
     ``--xla_force_host_platform_device_count``.
 
-    Must run before the jax backend initializes (importing jax is fine —
-    XLA_FLAGS is read at first backend use).  A caller-provided count in
-    ``XLA_FLAGS`` always wins; if the backend is already up with fewer
-    devices the flag is left alone so jax never sees a mid-process change.
-    Benchmarks and CI call this so ``place_operators`` round_robin has
-    real devices to spread enrichment operators over.
+    The platform is the CPU when ``JAX_PLATFORMS`` names only ``cpu``;
+    elsewhere the real devices are used as they are.  Must run before the
+    jax backend initializes (importing jax is fine — XLA_FLAGS is read at
+    first backend use).  A caller-provided count in ``XLA_FLAGS`` always
+    wins.  Benchmark entry points call this so ``place_operators``
+    round_robin has devices to spread enrichment operators over.
     """
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if {p.strip() for p in platforms.split(",")} != {"cpu"}:
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

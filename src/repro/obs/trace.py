@@ -23,8 +23,9 @@ The tracer can also bridge into ``jax.profiler``: ``annotations=True`` wraps
 every span in a :class:`jax.profiler.TraceAnnotation` (visible on the XLA
 trace timeline), and ``profiler_dir=...`` brackets the stream between
 ``jax.profiler.start_trace``/``stop_trace`` via
-:meth:`Tracer.start_profiler`/:meth:`Tracer.stop_profiler`.  Both are
-best-effort: absent profiler support degrades to plain host spans.
+:meth:`Tracer.start_profiler`/:meth:`Tracer.stop_profiler`.  Annotations
+are best-effort (absent profiler support degrades to plain host spans); a
+profiler trace that was asked for and cannot start raises.
 
 This module deliberately imports nothing from :mod:`repro.core` — it is a
 leaf utility the core wires in (see ``ExecutionConfig(trace=...)``), and
@@ -176,15 +177,22 @@ class Tracer:
 
     # -- jax.profiler bridge ------------------------------------------------
     def start_profiler(self) -> bool:
-        """Begin a ``jax.profiler`` trace into ``config.profiler_dir``
-        (best-effort; returns whether a trace actually started)."""
+        """Begin a ``jax.profiler`` trace into ``config.profiler_dir``.
+
+        Returns whether this call started a trace: ``False`` without a
+        ``profiler_dir`` or while this tracer's trace is already running.
+        A trace that was asked for and cannot start raises, so a run never
+        goes on silently untraced.
+        """
         if not self.config.profiler_dir or self._profiling:
             return False
         try:
             jax.profiler.start_trace(self.config.profiler_dir)
-            self._profiling = True
-        except Exception:
-            return False
+        except Exception as err:
+            raise RuntimeError(
+                "cannot start the jax.profiler trace into %r: %s"
+                % (self.config.profiler_dir, err)) from err
+        self._profiling = True
         return True
 
     def stop_profiler(self) -> None:

@@ -350,8 +350,7 @@ class ServeEngine:
             akb = self._kb_cache.get(specs)
             if akb is None:
                 akb = augment_kb_with_closures(
-                    query, kb, use_pallas=rcfg.use_pallas,
-                    interpret=rcfg.interpret)
+                    query, kb, use_pallas=rcfg.use_pallas)
                 self._kb_cache[specs] = akb
             if rcfg.kb_method == "auto":
                 kb_stats = self._kb_stats_cache.get(id(akb))
@@ -364,20 +363,18 @@ class ServeEngine:
             bind_cap=rcfg.bind_cap, out_cap=rcfg.out_cap,
             use_pallas=rcfg.use_pallas,
             fuse_compaction=rcfg.fuse_compaction,
-            join_bm=join_bm, join_bn=join_bn, interpret=rcfg.interpret,
-            kb_stats=kb_stats,
+            join_bm=join_bm, join_bn=join_bn, kb_stats=kb_stats,
         )
         # shared reasoning closure sets: one array per distinct
         # (subclass_pred, super_class); env dicts alias them
         env: Dict[str, jax.Array] = {}
         for item in query.where:
             if isinstance(item, Q.FilterSubclass):
-                ck = (item.subclass_pred, item.super_class,
-                      rcfg.use_pallas, rcfg.interpret)
+                ck = (item.subclass_pred, item.super_class, rcfg.use_pallas)
                 if ck not in self._env_cache:
                     _, arr = closure_env_entry(
                         akb, item.subclass_pred, item.super_class,
-                        rcfg.use_pallas, rcfg.interpret)
+                        rcfg.use_pallas)
                     self._env_cache[ck] = arr
                 env["closure:%d" % item.super_class] = self._env_cache[ck]
         if rcfg.kb_capacity and akb is not None:

@@ -89,11 +89,9 @@ def dp_train_step_compressed(loss_fn, opt_update, mesh: Mesh,
         metrics["loss"] = jax.lax.pmean(loss, axis_name)
         return params, opt_state, new_resid, metrics
 
-    from repro.compat import shard_map
-
     in_specs = (P(), P(), P(), P(axis_name))
     out_specs = (P(), P(), P(), P())
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

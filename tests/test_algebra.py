@@ -7,7 +7,9 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import algebra
 from repro.core.kb import kb_from_triples
-from repro.core.pattern import Bindings, CompiledPattern, Slot, empty_bindings
+from repro.core.pattern import (
+    Bindings, CompiledPattern, Slot, compact_rows, empty_bindings, prefix_count,
+)
 from repro.core.rdf import PAD_ID, Vocab, make_triples
 
 V = Vocab()
@@ -207,3 +209,95 @@ def test_join_matches_bruteforce(a_rows, b_rows):
         (s1, v, w) for (s1, v) in a_rows for (s2, w) in b_rows if s1 == s2
     )
     assert rows_of(out) == brute
+
+
+# --------------------------------------------------------------------------
+# the row-gathering join / rank-count sort / pairwise distinct against the
+# materialize-and-compact and lexsort formulations they replaced
+# --------------------------------------------------------------------------
+
+def _ref_pair_table(a, b, shared):
+    m = a.valid[:, None] & b.valid[None, :]
+    for c in shared:
+        m = m & (a.cols[:, None, c] == b.cols[None, :, c])
+    merged = jnp.maximum(a.cols[:, None, :], b.cols[None, :, :])
+    return m, merged.reshape(a.capacity * b.capacity, a.num_vars)
+
+
+def _ref_join(a, b, shared, cap):
+    m, merged = _ref_pair_table(a, b, shared)
+    return compact_rows(merged, m.reshape(-1), cap)
+
+
+def _ref_optional_join(a, b, shared, cap):
+    m, merged = _ref_pair_table(a, b, shared)
+    rows = jnp.concatenate([merged, a.cols])
+    mask = jnp.concatenate([m.reshape(-1), a.valid & ~jnp.any(m, axis=1)])
+    return compact_rows(rows, mask, cap)
+
+
+def _ref_canonical_order(b, sig_cols):
+    keys = tuple(b.cols[:, c] for c in reversed(sig_cols))
+    order = jnp.lexsort(keys + ((~b.valid).astype(jnp.uint32),))
+    return jnp.take(b.cols, order, axis=0), jnp.take(b.valid, order)
+
+
+def _ref_distinct(b, cap):
+    nv = b.num_vars
+    keys = tuple(b.cols[:, c] for c in range(nv - 1, -1, -1))
+    order = jnp.lexsort(keys + ((~b.valid).astype(jnp.uint32),))
+    sc, sv = jnp.take(b.cols, order, axis=0), jnp.take(b.valid, order)
+    prev = jnp.concatenate([jnp.zeros((1, nv), jnp.uint32), sc[:-1]])
+    new = jnp.any(sc != prev, axis=1) | (jnp.arange(b.capacity) == 0)
+    return compact_rows(b.cols, jnp.take(sv & new, jnp.argsort(order)), cap)
+
+
+def _random_bindings(rng, cap, nv, spread, wide=False):
+    cols = rng.integers(0, spread, (cap, nv)).astype(np.uint32)
+    if wide:                      # full uint32 range: ordering past 2^31
+        cols[:, 0] = rng.integers(0, 2**32, cap, dtype=np.uint64)
+    return Bindings(jnp.asarray(cols), jnp.asarray(rng.random(cap) < 0.7),
+                    jnp.zeros((), bool))
+
+
+def _assert_same(got, want, what):
+    np.testing.assert_array_equal(np.asarray(got.cols), np.asarray(want[0]),
+                                  err_msg=what)
+    np.testing.assert_array_equal(np.asarray(got.valid), np.asarray(want[1]),
+                                  err_msg=what)
+    if len(want) > 2:
+        assert bool(got.overflow) == bool(want[2]), what
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_joins_match_materialized_reference(seed):
+    rng = np.random.default_rng(seed)
+    ca, cb = (int(x) for x in rng.integers(1, 24, 2))
+    a = _random_bindings(rng, ca, 4, 4)
+    b = _random_bindings(rng, cb, 4, 4)
+    for cap in (1, 7, ca * cb + ca + 2):
+        for shared in ((), (0,), (0, 1)):
+            _assert_same(algebra.join(a, b, shared, cap),
+                         _ref_join(a, b, shared, cap), "join")
+            _assert_same(algebra.optional_join(a, b, shared, cap),
+                         _ref_optional_join(a, b, shared, cap), "optional")
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_sorting_operators_match_lexsort_reference(seed):
+    rng = np.random.default_rng(100 + seed)
+    cap, nv = int(rng.integers(1, 40)), int(rng.integers(1, 5))
+    b = _random_bindings(rng, cap, nv, 3, wide=seed % 2 == 1)
+    for sig in (tuple(range(nv)), tuple(reversed(range(nv))), (nv - 1,)):
+        _assert_same(algebra.canonical_order(b, sig),
+                     _ref_canonical_order(b, sig), "canonical_order")
+    for cap_out in (1, cap // 2 + 1, cap + 3):
+        _assert_same(algebra.distinct(b, cap_out), _ref_distinct(b, cap_out),
+                     "distinct")
+
+
+@pytest.mark.parametrize("n", [0, 1, 127, 128, 129, 1000, 70_000])
+def test_prefix_count_is_cumsum(n):
+    mask = np.random.default_rng(n).random(n) < 0.4
+    np.testing.assert_array_equal(np.asarray(prefix_count(jnp.asarray(mask))),
+                                  np.cumsum(mask, dtype=np.int32))

@@ -136,6 +136,21 @@ def test_spans_off_and_null_span_are_noop():
         assert sp.fence("v") == "v"
 
 
+def test_profiler_that_cannot_start_raises(tmp_path, monkeypatch):
+    """No profiler_dir: nothing to start.  A requested trace that fails to
+    start raises instead of letting the run go on untraced."""
+    assert Tracer(TraceConfig()).start_profiler() is False
+
+    def refuse(_dir):
+        raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    tr = Tracer(TraceConfig(profiler_dir=str(tmp_path)))
+    with pytest.raises(RuntimeError, match="profiler busy"):
+        tr.start_profiler()
+    tr.stop_profiler()                      # nothing was started: a no-op
+
+
 # --------------------------------------------------------------------------
 # metric units: merge conventions encoded in the key names
 # --------------------------------------------------------------------------

@@ -5,6 +5,7 @@ feeds and drains all three runtimes, and a cquery1 run produces
 **bit-identical** output streams across ``monolithic``, ``single_program``
 and ``pipelined`` modes.
 """
+import dataclasses
 import warnings
 
 import numpy as np
@@ -136,25 +137,61 @@ def test_execution_config_validates_mode_and_mesh():
         ExecutionConfig(mode="pipelined", mesh=object())
 
 
-def test_runtime_config_slice_carries_interpret():
-    cfg = ExecutionConfig(use_pallas=True, interpret=False)
-    rcfg = cfg.runtime_config()
-    assert rcfg.use_pallas and not rcfg.interpret
-    assert cfg.runtime_config().fuse_compaction is cfg.fuse_compaction
+def _spy_pallas(monkeypatch):
+    """Record the ``interpret`` every kernel launch asks for, and run the
+    launch through the interpreter so the test can check results on CPU."""
+    from jax.experimental import pallas as pl
+
+    seen = []
+    real = pl.pallas_call
+
+    def spy(*args, interpret=False, **kwargs):
+        seen.append(interpret)
+        return real(*args, interpret=True, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    return seen
 
 
-def test_interpret_knob_reaches_compiled_plan_steps(sworld):
-    """The ROADMAP open item: interpret must flow config -> plan -> KBJoin
-    without editing kernel source.  (q16 has no FilterSubclass, so plan
-    construction stays trace-free and interpret=False builds even on CPU.)"""
-    for interp in (True, False):
-        cfg = CFG.replace(mode="monolithic", use_pallas=True,
-                          fuse_compaction=True, interpret=interp)
-        reg = sworld.session(cfg).register(PQ.Q16_RQ)
-        steps = [s for s in reg.runtime.operator.plan.steps
-                 if isinstance(s, KBJoin)]
-        assert steps and all(s.interpret is interp for s in steps)
-        assert all(s.use_pallas for s in steps)
+def _q16_pallas_run(sworld, window_capacity):
+    cfg = CFG.replace(mode="monolithic", use_pallas=True, fuse_compaction=True,
+                      window_capacity=window_capacity)
+    reg = sworld.session(cfg).register(PQ.Q16_RQ)
+    steps = [s for s in reg.runtime.operator.plan.steps
+             if isinstance(s, KBJoin)]
+    assert steps and all(s.use_pallas for s in steps)
+    outs, _ = reg.run(sworld.chunks)
+    want, _ = sworld.session(cfg.replace(use_pallas=False)).register(
+        PQ.Q16_RQ).run(sworld.chunks)
+    assert_bit_identical(outs, want, "pallas vs jnp")
+
+
+def test_cpu_backend_interprets_kernels(sworld, monkeypatch):
+    """On the CPU backend every kernel launch of a Pallas session runs the
+    interpreter; the config has no knob that could say otherwise."""
+    import jax
+    from repro.kernels import resolve_interpret
+
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret() is True
+    assert "interpret" not in {f.name for f in dataclasses.fields(CFG)}
+    seen = _spy_pallas(monkeypatch)
+    _q16_pallas_run(sworld, window_capacity=97)
+    assert seen and all(i is True for i in seen)
+
+
+def test_tpu_backend_compiles_kernels(sworld, monkeypatch):
+    """With the platform query steered to a TPU, the same session asks
+    Mosaic to compile every kernel (``interpret=False``)."""
+    import jax
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False
+    assert resolve_interpret(True) is True
+    seen = _spy_pallas(monkeypatch)
+    _q16_pallas_run(sworld, window_capacity=98)
+    assert seen and all(i is False for i in seen)
 
 
 def test_register_duplicate_name_raises_with_both_texts(sworld):

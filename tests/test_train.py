@@ -85,13 +85,12 @@ def test_compressed_allreduce_single_device_mesh():
     mesh = jax.make_mesh((jax.device_count(),), ("data",))
     allreduce = make_compressed_allreduce(mesh, "data")
 
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     def run(g, r):
         return allreduce({"g": g}, {"g": r})
 
-    f = shard_map(run, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+    f = jax.shard_map(run, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
                   check_vma=False)
     g = jnp.asarray(np.linspace(-1, 1, 32, dtype=np.float32))
     mean, resid = f(g, jnp.zeros_like(g))
