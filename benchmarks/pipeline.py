@@ -91,7 +91,7 @@ def _stage_breakdown(world, base, q, chunks, query: str,
         for _ in range(passes):
             reg.run(chunks)
         stats = reg.last_stats
-        prefix = "stage" if mode == "pipelined" else "chunk"
+        prefix = "stage" if mode == "pipelined" else "dscep.chunk"
         breakdown[mode] = {
             "spans": stats["spans"],
             "operators": stats["operators"],

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.metrics import stat_max
+from repro.obs.trace import in_layer
 
 from .kb import KnowledgeBase, gather_matches, probe_range
 from .pattern import (
@@ -57,6 +58,7 @@ def _slot_match(slot, col_vals, bind_row=None):
     return jnp.ones_like(col_vals, dtype=bool)
 
 
+@in_layer("scan")
 def scan_pattern(
     window: TripleBatch, pat: CompiledPattern, num_vars: int, out_cap: int
 ) -> Bindings:
@@ -108,6 +110,7 @@ def _zero_invalid(rows: jax.Array, valid: jax.Array) -> jax.Array:
     return jnp.where(valid[:, None], rows, jnp.zeros_like(rows))
 
 
+@in_layer("stream_join")
 def join(a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int) -> Bindings:
     """Natural join on the static shared-variable columns.
 
@@ -120,6 +123,7 @@ def join(a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int) -> Bin
     return Bindings(rows, valid, overflow | a.overflow | b.overflow)
 
 
+@in_layer("stream_join")
 def union(a: Bindings, b: Bindings, out_cap: int) -> Bindings:
     rows = jnp.concatenate([a.cols, b.cols], axis=0)
     mask = jnp.concatenate([a.valid, b.valid], axis=0)
@@ -127,6 +131,7 @@ def union(a: Bindings, b: Bindings, out_cap: int) -> Bindings:
     return Bindings(out, valid, overflow | a.overflow | b.overflow)
 
 
+@in_layer("stream_join")
 def optional_join(
     a: Bindings, b: Bindings, shared: Tuple[int, ...], out_cap: int
 ) -> Bindings:
@@ -306,6 +311,7 @@ def kb_join_probe(
     return Bindings(rows, valid, any_overflow)
 
 
+@in_layer("kb_join")
 def kb_join(
     bind: Bindings, kb: KnowledgeBase, pat: CompiledPattern, out_cap: int,
     method: str = "scan", k_max: int = 8, use_pallas: bool = False,
@@ -586,12 +592,14 @@ def construct(
 SPAN_ENC_K = 0xFFFFFFFF
 
 
+@in_layer("delta")
 def delta_universe(capacity: int, num_vars: int) -> Bindings:
     """The BGP identity with empty span columns attached."""
     from .pattern import universe_bindings
     return universe_bindings(capacity, num_vars + 2)
 
 
+@in_layer("scan")
 def scan_pattern_delta(
     stream: TripleBatch, pat: CompiledPattern, num_vars: int, out_cap: int,
     slide_of_row: jax.Array,
@@ -626,6 +634,7 @@ def scan_pattern_delta(
     return Bindings(rows, valid, overflow)
 
 
+@in_layer("delta")
 def delta_retract(bind: Bindings, num_vars: int, max_span: int) -> Bindings:
     """Eagerly retract rows whose slide span exceeds ``max_span`` slides
     (0-based: a span of k means max_slide - min_slide == k).  Spans only
@@ -638,6 +647,7 @@ def delta_retract(bind: Bindings, num_vars: int, max_span: int) -> Bindings:
     return bind._replace(valid=bind.valid & keep)
 
 
+@in_layer("delta")
 def delta_window_mask(
     bind: Bindings, num_vars: int, window: jax.Array, slides_per_window: int,
 ) -> jax.Array:

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.metrics import reduce_stats, stat_add, stat_max
+from repro.obs.trace import in_layer, layer_scope
 
 from . import algebra
 from .kb import KnowledgeBase
@@ -187,21 +188,40 @@ def _binding_table(
     return Bindings(out, tvalid, jnp.zeros((), bool))
 
 
+_FILTER_STEPS = (FilterNumStep, FilterBoolStep, FilterInStep, DistinctStep,
+                 ProjectStep)
+
+
+@in_layer("filter")
+def _apply_filter(step: Step, cur: Bindings, env: Env) -> Bindings:
+    """The plan's steps that drop rows or columns of one relation."""
+    if isinstance(step, FilterNumStep):
+        return algebra.filter_num(cur, step.var, step.op, step.value_id)
+    if isinstance(step, FilterBoolStep):
+        return algebra.filter_bool(cur, step.expr)
+    if isinstance(step, FilterInStep):
+        return algebra.filter_in(cur, step.var, env[step.set_name])
+    if isinstance(step, DistinctStep):
+        return algebra.distinct(cur)
+    return algebra.project(cur, step.keep)
+
+
 def _apply(
     step: Step, cur: Bindings, window: TripleBatch, kb: Optional[KnowledgeBase],
     env: Env, plan: Plan, stats: Stats = None, tables: Tables = None,
 ) -> Bindings:
     if isinstance(step, BindingJoin):
-        b = _binding_table(step, tables, plan.num_vars)
-        if stats is not None:
-            stat_max(stats, "hw_scan", _occ(b))
-        if step.replace:
-            # first step: universe ⋈ T is T itself (shared is empty, the
-            # max-merge with all-PAD is the identity) — clip to bind_cap
-            # without the [1, rows] outer product
-            rows, valid, ovf = compact_rows(b.cols, b.valid, plan.bind_cap)
-            return Bindings(rows, valid, ovf | cur.overflow)
-        return algebra.join(cur, b, step.shared, plan.bind_cap)
+        with layer_scope("stream_join"):
+            b = _binding_table(step, tables, plan.num_vars)
+            if stats is not None:
+                stat_max(stats, "hw_scan", _occ(b))
+            if step.replace:
+                # first step: universe ⋈ T is T itself (shared is empty, the
+                # max-merge with all-PAD is the identity) — clip to bind_cap
+                # without the [1, rows] outer product
+                rows, valid, ovf = compact_rows(b.cols, b.valid, plan.bind_cap)
+                return Bindings(rows, valid, ovf | cur.overflow)
+            return algebra.join(cur, b, step.shared, plan.bind_cap)
     if isinstance(step, ScanJoin):
         b = algebra.scan_pattern(window, step.pat, plan.num_vars, plan.scan_cap)
         if stats is not None:
@@ -215,12 +235,8 @@ def _apply(
             fuse_compaction=step.fuse_compaction, bm=step.bm, bn=step.bn,
             stats=stats,
         )
-    if isinstance(step, FilterNumStep):
-        return algebra.filter_num(cur, step.var, step.op, step.value_id)
-    if isinstance(step, FilterBoolStep):
-        return algebra.filter_bool(cur, step.expr)
-    if isinstance(step, FilterInStep):
-        return algebra.filter_in(cur, step.var, env[step.set_name])
+    if isinstance(step, _FILTER_STEPS):
+        return _apply_filter(step, cur, env)
     if isinstance(step, OptionalSteps):
         sub = universe_bindings(plan.bind_cap, plan.num_vars)
         for s in step.sub:
@@ -234,10 +250,6 @@ def _apply(
         for s in step.right:
             right = _apply(s, right, window, kb, env, plan, stats, tables)
         return algebra.union(left, right, plan.bind_cap)
-    if isinstance(step, DistinctStep):
-        return algebra.distinct(cur)
-    if isinstance(step, ProjectStep):
-        return algebra.project(cur, step.keep)
     raise TypeError("unknown step %r" % (step,))
 
 
@@ -261,6 +273,7 @@ def run_steps(
     return cur
 
 
+@in_layer("finalize")
 def finalize_bindings(
     plan: Plan, cur: Bindings, ts: jax.Array,
     graph_base: jax.Array | int = 0, stats: Stats = None,
@@ -370,14 +383,15 @@ def _apply_delta(
     which is exactly the interval test ``delta_window_mask`` applies.
     """
     if isinstance(step, BindingJoin):
-        b = _binding_table(step, tables, plan.num_vars + 2, num_span=2)
-        if stats is not None:
-            stat_max(stats, "hw_scan", _occ(b))
-        if step.replace:
-            rows, valid, ovf = compact_rows(b.cols, b.valid, plan.bind_cap)
-            joined = Bindings(rows, valid, ovf | cur.overflow)
-        else:
-            joined = algebra.join(cur, b, step.shared, plan.bind_cap)
+        with layer_scope("stream_join"):
+            b = _binding_table(step, tables, plan.num_vars + 2, num_span=2)
+            if stats is not None:
+                stat_max(stats, "hw_scan", _occ(b))
+            if step.replace:
+                rows, valid, ovf = compact_rows(b.cols, b.valid, plan.bind_cap)
+                joined = Bindings(rows, valid, ovf | cur.overflow)
+            else:
+                joined = algebra.join(cur, b, step.shared, plan.bind_cap)
         retracted = algebra.delta_retract(joined, plan.num_vars, max_span)
         if stats is not None:
             stat_add(stats, "n_retract", _occ(joined) - _occ(retracted))
@@ -402,12 +416,8 @@ def _apply_delta(
             fuse_compaction=step.fuse_compaction, bm=step.bm, bn=step.bn,
             stats=stats,
         )
-    if isinstance(step, FilterNumStep):
-        return algebra.filter_num(cur, step.var, step.op, step.value_id)
-    if isinstance(step, FilterBoolStep):
-        return algebra.filter_bool(cur, step.expr)
-    if isinstance(step, FilterInStep):
-        return algebra.filter_in(cur, step.var, env[step.set_name])
+    if isinstance(step, (FilterNumStep, FilterBoolStep, FilterInStep)):
+        return _apply_filter(step, cur, env)
     if isinstance(step, UnionSteps):
         left = cur
         for s in step.left:
@@ -457,28 +467,22 @@ def run_plan_slides(
                            tables)
         if stats is not None:
             stat_max(stats, "hw_bind", _occ(cur))
-    out_vars = plan_out_vars(plan)
-    assert out_vars, (
+    assert plan_out_vars(plan), (
         "plan %s has no output variables — plan_supports_delta should have "
         "routed it to per-window recompute" % plan.name)
-    sig = tuple(sorted(out_vars, key=lambda c: plan.var_names[c]))
-    chunk_ovf = cur.overflow
 
-    widx = jnp.arange(max_windows)[:, None] + jnp.arange(r)[None, :]  # [W, R]
-    w_ts = jnp.max(jnp.take(view.slide_ts, widx, axis=0), axis=1)
-    w_valid = jnp.any(jnp.take(view.slide_valid, widx, axis=0), axis=1)
+    with layer_scope("delta"):
+        widx = jnp.arange(max_windows)[:, None] + jnp.arange(r)[None, :]
+        w_ts = jnp.max(jnp.take(view.slide_ts, widx, axis=0), axis=1)
+        w_valid = jnp.any(jnp.take(view.slide_valid, widx, axis=0), axis=1)
 
     def one(wid, ts, wvalid):
         memb = algebra.delta_window_mask(cur, plan.num_vars, wid, r)
-        rows = Bindings(cur.cols[:, : plan.num_vars], memb, chunk_ovf)
-        emit = algebra.canonical_order(
-            algebra.distinct(algebra.project(rows, out_vars)), sig)
-        out, c_ovf = algebra.construct(
-            emit, plan.templates, ts, plan.out_cap,
-            wid.astype(jnp.uint32) * plan.bind_cap,
-        )
+        rows = Bindings(cur.cols[:, : plan.num_vars], memb, cur.overflow)
+        out, ovf = finalize_bindings(
+            plan, rows, ts, wid.astype(jnp.uint32) * plan.bind_cap)
         out = out._replace(valid=out.valid & wvalid)
-        return out, chunk_ovf | emit.overflow | c_ovf
+        return out, ovf
 
     res = jax.vmap(one)(jnp.arange(max_windows), w_ts, w_valid)
     if not with_stats:
@@ -548,9 +552,10 @@ def run_plan_window_tables(
         stats: Stats = {} if with_stats else None
         cur = universe_bindings(plan.bind_cap, plan.num_vars)
         cur = run_steps(plan, cur, plan.steps, window, kb, env, stats)
-        emit = algebra.canonical_order(
-            algebra.distinct(algebra.project(cur, out_vars)), sig)
-        cols, valid, clipped = _clip_table(emit, pub_cols, rows_cap)
+        with layer_scope("finalize"):
+            emit = algebra.canonical_order(
+                algebra.distinct(algebra.project(cur, out_vars)), sig)
+            cols, valid, clipped = _clip_table(emit, pub_cols, rows_cap)
         valid = valid & wvalid
         ovf = cur.overflow | emit.overflow | clipped
         if with_stats:
@@ -591,10 +596,11 @@ def run_plan_slide_tables(
     out_vars = plan_out_vars(plan)
     # dedup over (variables, span): rows equal in both are interchangeable
     # for every window's interval test, so multiplicity can be dropped here
-    emit = algebra.distinct(
-        algebra.project(cur, tuple(out_vars) + (nv, nv + 1)))
-    cols, valid, clipped = _clip_table(
-        emit, tuple(pub_cols) + (nv, nv + 1), rows_cap)
+    with layer_scope("finalize"):
+        emit = algebra.distinct(
+            algebra.project(cur, tuple(out_vars) + (nv, nv + 1)))
+        cols, valid, clipped = _clip_table(
+            emit, tuple(pub_cols) + (nv, nv + 1), rows_cap)
     ovf = cur.overflow | emit.overflow | clipped
     if with_stats:
         stat_max(stats, "hw_out", jnp.sum(valid.astype(jnp.int32)))

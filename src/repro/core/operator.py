@@ -18,6 +18,8 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import in_layer
+
 from .engine import (
     Plan, run_plan_slide_tables, run_plan_slides, run_plan_window_tables,
     run_plan_windows, run_sink_slides, run_sink_windows,
@@ -32,6 +34,7 @@ from .window import (
 )
 
 
+@in_layer("publish")
 def publish_chunk(out_w: TripleBatch, out_stream_cap: int) -> TripleBatch:
     """Publisher: flatten ``[W, cap]`` window outputs into one ordered chunk
     (order-preserving compaction of valid triples to the front).  Module

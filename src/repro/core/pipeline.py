@@ -143,7 +143,7 @@ class PipelinedRuntime:
         self.mesh = mesh
         self.data_axis = data_axis
         self.channel_capacity = channel_capacity
-        self.operators = build_operators(dag, kb, cfg)
+        self.operators = build_operators(dag, kb, cfg, tracer)
         self.final = dag.final
         # upstream operators in DAG insertion order — the same order
         # DSCEPRuntime._dag_impl iterates (augment_windows keys by name, so
@@ -168,7 +168,8 @@ class PipelinedRuntime:
         # *tables*, the sink joins them directly (None -> augmented path).
         # Swap the sink operator's plan so EXPLAIN/last_stats report the
         # plan that actually runs.
-        self._split = prepare_split_sink(dag, self.operators, cfg, mesh)
+        with span_or_null(tracer, "dscep.split_sink"):
+            self._split = prepare_split_sink(dag, self.operators, cfg, mesh)
         if self._split is not None:
             self.operators[self.final].plan = self._split.plan
 

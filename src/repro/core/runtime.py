@@ -118,11 +118,13 @@ def _warn_legacy_constructor(name: str, mode: str) -> None:
 
 
 def build_operators(
-    dag: OperatorDAG, kb: KnowledgeBase, config: RuntimeConfig
+    dag: OperatorDAG, kb: KnowledgeBase, config: RuntimeConfig,
+    tracer: Optional[Tracer] = None,
 ) -> Dict[str, SCEPOperator]:
     """Compile one :class:`SCEPOperator` per DAG node (shared by the
     single-program :class:`DSCEPRuntime` and the streaming
-    :class:`~repro.core.pipeline.PipelinedRuntime`)."""
+    :class:`~repro.core.pipeline.PipelinedRuntime`).  Each registration
+    step is a ``dscep.*`` span on ``tracer``, per operator."""
     op_cfg = OperatorConfig(
         window_capacity=config.window_capacity,
         max_windows=config.max_windows,
@@ -144,26 +146,31 @@ def build_operators(
         op_kb = None
         kb_stats = None
         if sub.touches_kb:
-            op_kb = prune_kb_for(sub.query, kb)
-            op_kb = augment_kb_with_closures(
-                sub.query, op_kb, use_pallas=config.use_pallas)
+            with span_or_null(tracer, "dscep.prune", operator=name):
+                op_kb = prune_kb_for(sub.query, kb)
+            with span_or_null(tracer, "dscep.closures", operator=name):
+                op_kb = augment_kb_with_closures(
+                    sub.query, op_kb, use_pallas=config.use_pallas)
             if config.kb_method == "auto":
-                kb_stats = collect_kb_stats(op_kb)
+                with span_or_null(tracer, "dscep.kb_stats", operator=name):
+                    kb_stats = collect_kb_stats(op_kb)
             if config.kb_capacity:
                 op_kb = pad_to(op_kb, config.kb_capacity)
-        plan = compile_query(
-            sub.query,
-            kb_method=config.kb_method,
-            scan_cap=config.scan_cap,
-            bind_cap=config.bind_cap,
-            out_cap=(config.out_cap if name == dag.final
-                     else min(config.intermediate_cap, config.out_cap)),
-            use_pallas=config.use_pallas,
-            fuse_compaction=config.fuse_compaction,
-            join_bm=join_bm, join_bn=join_bn,
-            kb_stats=kb_stats,
-        )
-        env = prepare_env(sub.query, kb, use_pallas=config.use_pallas)
+        with span_or_null(tracer, "dscep.plan", operator=name):
+            plan = compile_query(
+                sub.query,
+                kb_method=config.kb_method,
+                scan_cap=config.scan_cap,
+                bind_cap=config.bind_cap,
+                out_cap=(config.out_cap if name == dag.final
+                         else min(config.intermediate_cap, config.out_cap)),
+                use_pallas=config.use_pallas,
+                fuse_compaction=config.fuse_compaction,
+                join_bm=join_bm, join_bn=join_bn,
+                kb_stats=kb_stats,
+            )
+        with span_or_null(tracer, "dscep.env", operator=name):
+            env = prepare_env(sub.query, kb, use_pallas=config.use_pallas)
         operators[name] = SCEPOperator(name, plan, op_kb, env, op_cfg)
     return operators
 
@@ -285,17 +292,20 @@ class DSCEPRuntime:
         self.mesh = mesh
         self.data_axis = data_axis
         self.vocab = vocab
-        self.operators = build_operators(dag, kb, config)
+        self.operators = build_operators(dag, kb, config, tracer)
         # split aggregation sink: upstream operators ship binding tables,
         # the sink joins them directly (None -> augmented-window path).
         # The sink operator's plan is swapped for the rewritten one so
         # every introspection surface (EXPLAIN, plan_caps, last_stats)
         # reports the plan that actually runs.
-        self._split = prepare_split_sink(dag, self.operators, config, mesh)
+        with span_or_null(tracer, "dscep.split_sink"):
+            self._split = prepare_split_sink(dag, self.operators, config,
+                                             mesh)
         if self._split is not None:
             self.operators[dag.final].plan = self._split.plan
         self._jit_chunk = jax.jit(self._dag_impl)
         self.tracer = tracer
+        self._seq = 0
         self._collect = bool(tracer is not None and tracer.config.metrics)
         self._jit_chunk_stats = (
             jax.jit(functools.partial(self._dag_impl, with_stats=True))
@@ -429,17 +439,23 @@ class DSCEPRuntime:
         """Push one stream chunk through the DAG; returns (final output, overflow)."""
         kbs = {n: op.kb for n, op in self.operators.items()}
         envs = {n: op.env for n, op in self.operators.items()}
-        with span_or_null(self.tracer, "chunk", mode="single_program") as sp:
-            if self._collect:
-                out, ovf, stats = self._jit_chunk_stats(chunk, kbs, envs)
-                for name, st in stats.items():
-                    merge_stats(self._stats_acc[name], st)
-            else:
-                out, ovf = self._jit_chunk(chunk, kbs, envs)
-            sp.fence(out)
-        for name, flags in ovf.items():
-            self._overflow_acc[name] = (
-                self._overflow_acc[name] + jnp.sum(flags.astype(jnp.int32)))
+        tr = self.tracer
+        seq, self._seq = self._seq, self._seq + 1
+        with span_or_null(tr, "dscep.chunk", seq=seq, mode="single_program"):
+            with span_or_null(tr, "dscep.dispatch", seq=seq) as sp:
+                if self._collect:
+                    out, ovf, stats = self._jit_chunk_stats(chunk, kbs, envs)
+                else:
+                    out, ovf = self._jit_chunk(chunk, kbs, envs)
+                sp.fence(out)
+            with span_or_null(tr, "dscep.account", seq=seq):
+                if self._collect:
+                    for name, st in stats.items():
+                        merge_stats(self._stats_acc[name], st)
+                for name, flags in ovf.items():
+                    self._overflow_acc[name] = (
+                        self._overflow_acc[name]
+                        + jnp.sum(flags.astype(jnp.int32)))
         return out, ovf
 
     def process_stream(
@@ -510,18 +526,22 @@ class MonolithicRuntime:
         config = config if config is not None else RuntimeConfig()
         join_bm, join_bn = config.join_block_shapes or (None, None)
         # closure-pair relations for variable-length paths (no-op otherwise)
-        kb = augment_kb_with_closures(q, kb, use_pallas=config.use_pallas)
-        plan = compile_query(
-            q, kb_method=config.kb_method, scan_cap=config.scan_cap,
-            bind_cap=config.bind_cap, out_cap=config.out_cap,
-            use_pallas=config.use_pallas,
-            fuse_compaction=config.fuse_compaction,
-            join_bm=join_bm, join_bn=join_bn,
-            kb_stats=(collect_kb_stats(kb)
-                      if config.kb_method == "auto" and kb is not None
-                      else None),
-        )
-        env = prepare_env(q, kb, use_pallas=config.use_pallas)
+        with span_or_null(tracer, "dscep.closures", operator=q.name):
+            kb = augment_kb_with_closures(q, kb, use_pallas=config.use_pallas)
+        kb_stats = None
+        if config.kb_method == "auto" and kb is not None:
+            with span_or_null(tracer, "dscep.kb_stats", operator=q.name):
+                kb_stats = collect_kb_stats(kb)
+        with span_or_null(tracer, "dscep.plan", operator=q.name):
+            plan = compile_query(
+                q, kb_method=config.kb_method, scan_cap=config.scan_cap,
+                bind_cap=config.bind_cap, out_cap=config.out_cap,
+                use_pallas=config.use_pallas,
+                fuse_compaction=config.fuse_compaction,
+                join_bm=join_bm, join_bn=join_bn, kb_stats=kb_stats,
+            )
+        with span_or_null(tracer, "dscep.env", operator=q.name):
+            env = prepare_env(q, kb, use_pallas=config.use_pallas)
         if config.kb_capacity:
             kb = pad_to(kb, config.kb_capacity)
         self.operator = SCEPOperator(
@@ -535,17 +555,24 @@ class MonolithicRuntime:
         self._collect = bool(tracer is not None and tracer.config.metrics)
         self._overflow_acc = jnp.zeros((), jnp.int32)
         self._stats_acc: Dict[str, jax.Array] = {}
+        self._seq = 0
 
     def process_chunk(self, chunk: TripleBatch) -> Tuple[TripleBatch, jax.Array]:
         op = self.operator
-        with span_or_null(self.tracer, "chunk", mode="monolithic") as sp:
-            if self._collect:
-                out, ovf, stats = op.process_stats([chunk])
-                merge_stats(self._stats_acc, stats)
-            else:
-                out, ovf = op.process([chunk])
-            sp.fence(out)
-        self._overflow_acc = self._overflow_acc + jnp.sum(ovf.astype(jnp.int32))
+        tr = self.tracer
+        seq, self._seq = self._seq, self._seq + 1
+        with span_or_null(tr, "dscep.chunk", seq=seq, mode="monolithic"):
+            with span_or_null(tr, "dscep.dispatch", seq=seq) as sp:
+                if self._collect:
+                    out, ovf, stats = op.process_stats([chunk])
+                else:
+                    out, ovf = op.process([chunk])
+                sp.fence(out)
+            with span_or_null(tr, "dscep.account", seq=seq):
+                if self._collect:
+                    merge_stats(self._stats_acc, stats)
+                self._overflow_acc = (self._overflow_acc
+                                      + jnp.sum(ovf.astype(jnp.int32)))
         return out, ovf
 
     # -- observability surfaces (uniform across all three runtimes) ----------

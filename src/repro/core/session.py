@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.report import attach_saturation
-from repro.obs.trace import TraceConfig, Tracer, resolve_trace
+from repro.obs.trace import TraceConfig, Tracer, resolve_trace, span_or_null
 
 from . import query as Q
 from .faults import FaultPlan
@@ -199,7 +199,8 @@ class RegisteredQuery:
         self.dag: Optional[OperatorDAG] = None
         tcfg = resolve_trace(cfg.trace)
         self.tracer: Optional[Tracer] = Tracer(tcfg) if tcfg else None
-        self._runtime = self._build_runtime()
+        with span_or_null(self.tracer, "dscep.register", query=query.name):
+            self._runtime = self._build_runtime()
 
     @property
     def window_geometry(self) -> Tuple[int, Optional[int]]:

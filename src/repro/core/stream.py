@@ -13,9 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import in_layer
+
 from .rdf import TripleBatch, concat_triples, sort_by_timestamp
 
 
+@in_layer("pack")
 def merge_streams(chunks: Sequence[TripleBatch]) -> TripleBatch:
     """Merge K stream chunks into one timestamp-ordered chunk.
 

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import in_layer
+
 from .rdf import TripleBatch, take_rows
 
 
@@ -176,6 +178,7 @@ def _scatter_units(
     return slot_of_row[: max_units * capacity].reshape(max_units, capacity)
 
 
+@in_layer("pack")
 def count_slides(
     stream: TripleBatch, window_capacity: int, max_windows: int,
     step: Optional[int] = None,
@@ -201,6 +204,7 @@ def count_slides(
     )
 
 
+@in_layer("pack")
 def windows_from_slides(
     view: SlideView, window_capacity: int, max_windows: int,
     step: Optional[int] = None,
@@ -226,6 +230,7 @@ def windows_from_slides(
     return Windows(wt, window_valid)
 
 
+@in_layer("pack")
 def count_windows(
     stream: TripleBatch, window_capacity: int, max_windows: int,
     step: Optional[int] = None,

@@ -19,48 +19,75 @@ Latency attribution in a JAX pipeline has two classic traps:
 Spans nest: a span opened while another is active records under the path
 ``outer/inner``, giving per-stage attribution inside a chunk-level span.
 
-The tracer can also bridge into ``jax.profiler``: ``annotations=True`` wraps
-every span in a :class:`jax.profiler.TraceAnnotation` (visible on the XLA
-trace timeline), and ``profiler_dir=...`` brackets the stream between
-``jax.profiler.start_trace``/``stop_trace`` via
-:meth:`Tracer.start_profiler`/:meth:`Tracer.stop_profiler`.  Annotations
-are best-effort (absent profiler support degrades to plain host spans); a
-profiler trace that was asked for and cannot start raises.
+Every recorded span is also a :class:`jax.profiler.TraceAnnotation`, so
+when a ``jax.profiler`` trace runs, the program's spans sit on the same
+clock as the device's ops (an annotation costs almost nothing while no
+profiler runs).  Inside the jitted programs, :func:`layer_scope` names
+each engine layer (``jax.named_scope("dscep.<layer>")``, one of
+:data:`LAYERS`): the scope lands in every op's ``op_name`` metadata, not
+in the jaxpr, and is always on.
 
 This module deliberately imports nothing from :mod:`repro.core` — it is a
 leaf utility the core wires in (see ``ExecutionConfig(trace=...)``), and
-with tracing off the runtimes never touch it on the hot path.
+with tracing off the runtimes touch only its layer scopes, and those only
+while a program is traced.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Callable, Dict, List, Optional, Union
 
 import jax
+
+
+# The engine layers a device op can belong to.  An op's layer is the
+# innermost ``dscep.<layer>`` scope in its ``op_name`` (a KB join inside an
+# OPTIONAL is ``kb_join``).
+LAYERS = ("pack", "scan", "stream_join", "kb_join", "filter", "delta",
+          "finalize", "publish")
+SCOPE_PREFIX = "dscep."
+
+
+def layer_scope(layer: str):
+    """``jax.named_scope("dscep.<layer>")`` for one of :data:`LAYERS`."""
+    if layer not in LAYERS:
+        raise ValueError("unknown engine layer %r (have: %s)"
+                         % (layer, ", ".join(LAYERS)))
+    return jax.named_scope(SCOPE_PREFIX + layer)
+
+
+def in_layer(layer: str) -> Callable[[Callable], Callable]:
+    """Decorator: run the function under :func:`layer_scope` ``(layer)``."""
+    layer_scope(layer)          # reject an unknown layer at definition time
+
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with layer_scope(layer):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 @dataclasses.dataclass(frozen=True)
 class TraceConfig:
     """Frozen observability knobs (hashable, safe as a jit-static field).
 
-    ``spans``       — record host wall-time spans;
+    ``spans``       — record host wall-time spans (each also a
+                      ``jax.profiler.TraceAnnotation``);
     ``metrics``     — collect device-side engine metrics (binding/scan
                       occupancy high-water, probe saturation, retractions)
                       in the jitted step's carry;
     ``fence``       — ``block_until_ready`` span fences so durations cover
-                      device work (serializes overlapped stages);
-    ``annotations`` — wrap spans in ``jax.profiler.TraceAnnotation``;
-    ``profiler_dir``— directory for ``jax.profiler.start_trace`` output
-                      (enables :meth:`Tracer.start_profiler`).
+                      device work (serializes overlapped stages).
     """
 
     spans: bool = True
     metrics: bool = True
     fence: bool = True
-    annotations: bool = False
-    profiler_dir: Optional[str] = None
 
 
 def resolve_trace(trace: Union[None, bool, TraceConfig]) -> Optional[TraceConfig]:
@@ -129,7 +156,6 @@ class Tracer:
         self._samples: Dict[str, List[float]] = {}
         self._meta: Dict[str, Dict[str, Any]] = {}
         self._stack: List[str] = []
-        self._profiling = False
 
     @property
     def enabled(self) -> bool:
@@ -154,13 +180,8 @@ class Tracer:
         path = "/".join(self._stack + [name])
         self._stack.append(name)
         handle = _SpanHandle()
-        ann = None
-        if self.config.annotations:
-            try:
-                ann = jax.profiler.TraceAnnotation(path)
-                ann.__enter__()
-            except Exception:
-                ann = None
+        ann = jax.profiler.TraceAnnotation(name, **meta)
+        ann.__enter__()
         t0 = time.perf_counter()
         try:
             yield handle
@@ -168,39 +189,11 @@ class Tracer:
             if handle._fence is not None and self.config.fence:
                 jax.block_until_ready(handle._fence)
             dur = time.perf_counter() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
+            ann.__exit__(None, None, None)
             self._stack.pop()
             self._samples.setdefault(path, []).append(dur)
             if meta:
                 self._meta.setdefault(path, {}).update(meta)
-
-    # -- jax.profiler bridge ------------------------------------------------
-    def start_profiler(self) -> bool:
-        """Begin a ``jax.profiler`` trace into ``config.profiler_dir``.
-
-        Returns whether this call started a trace: ``False`` without a
-        ``profiler_dir`` or while this tracer's trace is already running.
-        A trace that was asked for and cannot start raises, so a run never
-        goes on silently untraced.
-        """
-        if not self.config.profiler_dir or self._profiling:
-            return False
-        try:
-            jax.profiler.start_trace(self.config.profiler_dir)
-        except Exception as err:
-            raise RuntimeError(
-                "cannot start the jax.profiler trace into %r: %s"
-                % (self.config.profiler_dir, err)) from err
-        self._profiling = True
-        return True
-
-    def stop_profiler(self) -> None:
-        if self._profiling:
-            try:
-                jax.profiler.stop_trace()
-            finally:
-                self._profiling = False
 
     # -- aggregation ---------------------------------------------------------
     def reset(self) -> None:

@@ -8,6 +8,7 @@ changes measured programs but never results — traced runs stay
 bit-identical to untraced runs in all three execution modes.
 """
 import json
+import re
 import time
 
 import jax
@@ -28,7 +29,10 @@ from repro.obs.report import (
     attach_saturation, bottleneck_stage, format_explain, format_metrics_table,
     format_stage_table, to_json,
 )
-from repro.obs.trace import TraceConfig, Tracer, resolve_trace, span_or_null
+from repro.obs.trace import (
+    LAYERS, TraceConfig, Tracer, in_layer, layer_scope, resolve_trace,
+    span_or_null,
+)
 
 CFG = ExecutionConfig(window_capacity=96, max_windows=4, bind_cap=1024,
                       scan_cap=128, out_cap=1024, intermediate_cap=512)
@@ -136,19 +140,41 @@ def test_spans_off_and_null_span_are_noop():
         assert sp.fence("v") == "v"
 
 
-def test_profiler_that_cannot_start_raises(tmp_path, monkeypatch):
-    """No profiler_dir: nothing to start.  A requested trace that fails to
-    start raises instead of letting the run go on untraced."""
-    assert Tracer(TraceConfig()).start_profiler() is False
+def test_spans_are_profiler_annotations(tmp_path):
+    """Every span is a jax.profiler TraceAnnotation: a running profiler
+    trace holds it on the host plane, with the span's metadata."""
+    from jax.profiler import ProfileData
 
-    def refuse(_dir):
-        raise RuntimeError("profiler busy")
+    tr = Tracer(TraceConfig(fence=False))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("dscep.chunk", seq=7):
+            with tr.span("dscep.dispatch", seq=7):
+                jax.block_until_ready(jax.numpy.ones(4))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("dscep."):
+                    found[ev.name] = (ev.start_ns, ev.duration_ns,
+                                      dict(ev.stats))
+    assert set(found) == {"dscep.chunk", "dscep.dispatch"}
+    (c0, cdur, cmeta), (d0, ddur, dmeta) = (found["dscep.chunk"],
+                                            found["dscep.dispatch"])
+    assert cmeta == dmeta == {"seq": 7}
+    assert c0 <= d0 and d0 + ddur <= c0 + cdur
 
-    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
-    tr = Tracer(TraceConfig(profiler_dir=str(tmp_path)))
-    with pytest.raises(RuntimeError, match="profiler busy"):
-        tr.start_profiler()
-    tr.stop_profiler()                      # nothing was started: a no-op
+
+def test_layer_scope_takes_only_engine_layers():
+    with layer_scope("kb_join"):
+        pass
+    with pytest.raises(ValueError, match="unknown engine layer"):
+        layer_scope("kb")
+    with pytest.raises(ValueError, match="unknown engine layer"):
+        in_layer("joins")
 
 
 # --------------------------------------------------------------------------
@@ -354,6 +380,111 @@ def test_traced_outputs_bit_identical_to_untraced(oworld):
         outs_on, ovf_on = on.run(oworld.chunks)
         assert_bit_identical(outs_off, outs_on, mode)
         assert ovf_off == ovf_on
+
+
+# --------------------------------------------------------------------------
+# the program's own spans and scopes
+# --------------------------------------------------------------------------
+
+SPANS_ONLY = TraceConfig(spans=True, metrics=False, fence=False)
+REGISTER_STEPS = ("dscep.prune", "dscep.closures", "dscep.kb_stats",
+                  "dscep.plan", "dscep.env", "dscep.split_sink")
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_registration_spans_nest_under_register(oworld, mode):
+    cfg = CFG.replace(mode=mode, kb_method="auto")
+    reg = oworld.session(cfg.replace(trace=SPANS_ONLY)).register(
+        PQ.CQUERY1_RQ)
+    spans = reg.last_stats["spans"]
+    top = spans["dscep.register"]
+    assert top["count"] == 1 and top["meta"] == {"query": reg.query.name}
+    children = {p.split("/", 1)[1]: s for p, s in spans.items()
+                if p.startswith("dscep.register/")}
+    ops = reg.operators
+    if mode == "monolithic":
+        expect = {"dscep.closures", "dscep.kb_stats", "dscep.plan",
+                  "dscep.env"}
+    else:
+        expect = set(REGISTER_STEPS)
+    assert set(children) == expect
+    kb_ops = [n for n, op in ops.items() if op.kb is not None]
+    for name, s in children.items():
+        n = 1 if name == "dscep.split_sink" else (
+            len(kb_ops) if name in ("dscep.prune", "dscep.closures",
+                                    "dscep.kb_stats") and mode != "monolithic"
+            else len(ops))
+        assert s["count"] == n, name
+        total = s["first_s"] + s["steady"]["total_s"]
+        assert 0 <= total <= top["first_s"], name
+        if name != "dscep.split_sink":
+            assert s["meta"]["operator"] in ops
+    # the same registration untraced: no tracer, no span
+    off = oworld.session(cfg).register(PQ.CQUERY1_RQ, name="untraced")
+    assert off.tracer is None
+    assert off.last_stats["spans"] == {}
+
+
+@pytest.mark.parametrize("mode", ("monolithic", "single_program"))
+def test_chunk_spans_split_dispatch_and_account(oworld, mode):
+    reg = oworld.session(CFG.replace(mode=mode, trace=SPANS_ONLY)).register(
+        PQ.CQUERY1_RQ)
+    reg.run(oworld.chunks)
+    spans = reg.last_stats["spans"]
+    n = len(oworld.chunks)
+    chunk = spans["dscep.chunk"]
+    assert chunk["count"] == n
+    assert chunk["meta"] == {"seq": n - 1, "mode": mode}
+    for child in ("dispatch", "account"):
+        s = spans["dscep.chunk/dscep.%s" % child]
+        assert s["count"] == n and s["meta"] == {"seq": n - 1}
+        assert s["steady"]["total_s"] <= chunk["steady"]["total_s"]
+
+
+# Q15 and Q16 on one mentioned entity, in 75%-overlap sliding windows
+Q15Q16_RQ = """\
+REGISTER QUERY q15q16 AS
+PREFIX schema: <urn:dscep:schema>
+PREFIX rdf: <http://www.w3.org/1999/02/22-rdf-syntax-ns#>
+PREFIX rdfs: <http://www.w3.org/2000/01/rdf-schema#>
+PREFIX dbo: <http://dbpedia.org/ontology/>
+PREFIX out: <urn:dscep:out>
+CONSTRUCT { ?tweet out:artistCode ?cc . }
+FROM STREAM <stream> [RANGE TRIPLES 96 STEP 24]
+FROM <kb>
+WHERE {
+  ?tweet schema:mentions ?ent .
+  GRAPH <kb> {
+    ?ent rdf:type/rdfs:subClassOf* dbo:MusicalArtist .
+    ?ent dbo:birthPlace/dbo:country/dbo:countryCode ?cc .
+  }
+}
+"""
+
+
+def _hlo_layers(text):
+    """The ``dscep.<layer>`` scopes named in a compiled program's op_names."""
+    return set(re.findall(r'op_name="[^"]*?dscep\.(\w+)', text))
+
+
+@pytest.mark.parametrize("query,cfg,expect", [
+    (PQ.CQUERY1_RQ, CFG,
+     {"pack", "scan", "stream_join", "kb_join", "filter", "finalize",
+      "publish"}),
+    (Q15Q16_RQ, CFG.replace(window_step=24, incremental=True),
+     {"pack", "scan", "stream_join", "kb_join", "delta", "finalize",
+      "publish"}),
+], ids=["cquery1", "q15q16"])
+def test_chunk_program_names_every_layer_it_runs(oworld, query, cfg, expect):
+    """The single-program chunk step, compiled: each engine layer the plan
+    runs shows as a dscep.<layer> scope in the ops' op_name metadata."""
+    reg = oworld.session(cfg.replace(mode="single_program")).register(query)
+    rt = reg.runtime
+    kbs = {n: op.kb for n, op in rt.operators.items()}
+    envs = {n: op.env for n, op in rt.operators.items()}
+    text = rt._jit_chunk.lower(oworld.chunks[0], kbs, envs).compile().as_text()
+    found = _hlo_layers(text)
+    assert expect <= found <= set(LAYERS)
 
 
 # --------------------------------------------------------------------------
