@@ -98,17 +98,15 @@ def universe_bindings(capacity: int, num_vars: int) -> Bindings:
 
 
 _PREFIX_BLOCK = 128
+# Largest ``[out_cap, blocks]`` compare-and-count that compact_index's top
+# level does densely; past it the search gains a level (see compact_index).
+_DENSE_TOP = 1 << 24
 
 
-def prefix_count(mask: jax.Array) -> jax.Array:
-    """Inclusive running count of a 1-D bool ``mask``, as int32.
-
-    Equal to ``cumsum(mask)``, computed in two levels: a triangular-ones
-    matmul within 128-wide blocks (0/1 inputs and counts up to 128 are exact
-    in bf16 x bf16 -> f32), plus a cumsum over the block totals.  A plain
-    cumsum over a million entries takes the TPU compiler over 20 s; this
-    takes under one.
-    """
+def _prefix_blocks(mask: jax.Array) -> jax.Array:
+    """Inclusive running count of a 1-D bool ``mask`` as ``[nb, 128]`` int32
+    blocks; the padding past ``n`` holds the total, and column 127 holds the
+    running count at each block's end."""
     n = mask.shape[0]
     b = _PREFIX_BLOCK
     nb = -(-n // b)
@@ -118,7 +116,20 @@ def prefix_count(mask: jax.Array) -> jax.Array:
                      preferred_element_type=jnp.float32).astype(jnp.int32)
     totals = within[:, -1]
     before = jnp.cumsum(totals) - totals
-    return (within + before[:, None]).reshape(nb * b)[:n]
+    return within + before[:, None]
+
+
+def prefix_count(mask: jax.Array) -> jax.Array:
+    """Inclusive running count of a 1-D bool ``mask``, as int32.
+
+    Equal to ``cumsum(mask)``, computed in 128-wide blocks: a
+    triangular-ones matmul within each block (0/1 inputs and counts up to
+    128 are exact in bf16 x bf16 -> f32), plus a cumsum over the block
+    totals.  A plain cumsum over a million entries takes the TPU compiler
+    over 20 s; this takes under one.  :func:`compact_index` searches the
+    same blocks.
+    """
+    return _prefix_blocks(mask).reshape(-1)[:mask.shape[0]]
 
 
 def compact_rows(
@@ -154,10 +165,32 @@ def compact_index(
     order (``src`` past them points anywhere in range).  For a mask over a
     virtual product, the caller gathers only the ``out_cap`` winning rows
     instead of materializing every candidate row.
+
+    Output ``k`` is the first position whose running count reaches
+    ``k + 1``, found in :func:`prefix_count`'s 128-wide blocks with no
+    loop: the top level counts, for every ``k`` at once, the block ends
+    below ``k + 1`` (a dense ``[out_cap, blocks]`` compare); each level
+    below gathers the found block's 128 counts as one row and adds the
+    count of them below ``k + 1``.  Where ``out_cap x blocks`` would pass
+    ``_DENSE_TOP``, the block ends are themselves grouped 128 to a block,
+    one level more, until it does not (read from the static shapes).
     """
-    cum = prefix_count(mask)
-    total = cum[-1]
+    n = mask.shape[0]
+    b = _PREFIX_BLOCK
     k = jnp.arange(out_cap, dtype=jnp.int32)
-    src = jnp.searchsorted(cum, k + 1, side="left").astype(jnp.int32)
-    src = jnp.minimum(src, mask.shape[0] - 1)
+    if n == 0:
+        return jnp.zeros((out_cap,), jnp.int32), k < 0, jnp.zeros((), bool)
+    levels = [_prefix_blocks(mask)]
+    total = levels[0][-1, -1]
+    while levels[-1].shape[0] > 1 and out_cap * levels[-1].shape[0] > _DENSE_TOP:
+        ends = levels[-1][:, -1]
+        nb = -(-ends.shape[0] // b)
+        levels.append(jnp.pad(ends, (0, nb * b - ends.shape[0]),
+                              mode="edge").reshape(nb, b))
+    want = (k + 1)[:, None]
+    src = jnp.sum(levels[-1][None, :, -1] < want, axis=1, dtype=jnp.int32)
+    for blocks in reversed(levels):
+        row = jnp.take(blocks, src, axis=0, mode="clip")
+        src = src * b + jnp.sum(row < want, axis=1, dtype=jnp.int32)
+    src = jnp.minimum(src, n - 1)
     return src, k < jnp.minimum(total, out_cap), total > out_cap

@@ -16,7 +16,7 @@ Three join surfaces:
   ``algebra.kb_join_probe`` pipeline.
 
 Each fused pair differs only in how it finds the sources of the first
-``out_cap`` matches (the kernel's walk, or a searchsorted over the jnp
+``out_cap`` matches (the kernel's walk, or a block search over the jnp
 cumulative count); both then gather those rows the same way.  All are
 bit-identical to the unfused ``match -> extend -> compact_rows`` pipeline,
 including row order (global row-major), zeroed invalid rows, and the
@@ -196,7 +196,7 @@ def probe_compact_jnp(
     """Fused jnp probe twin: gather the ``out_cap`` winners directly.
 
     Same move as :func:`join_compact_jnp` applied to the probe method: the
-    k-th output row is located by binary search on the cumulative match
+    k-th output row is located by a block search on the cumulative match
     count over the ``[cap, k_max]`` candidate block, so the row extension
     is built only for rows that actually publish.
     """
@@ -216,7 +216,7 @@ def join_compact_jnp(
 ) -> Bindings:
     """Fused jnp join: gather the out_cap winners instead of compacting M*N.
 
-    The k-th output row is located by binary search on the running match
+    The k-th output row is located by a block search on the running match
     count over the flattened row-major matrix
     (:func:`repro.core.pattern.compact_index`), so only ``out_cap`` extended
     rows are ever built.

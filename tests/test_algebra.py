@@ -1,14 +1,17 @@
+import functools
 import itertools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import algebra
+from repro.core import algebra, pattern
 from repro.core.kb import kb_from_triples
 from repro.core.pattern import (
-    Bindings, CompiledPattern, Slot, compact_rows, empty_bindings, prefix_count,
+    Bindings, CompiledPattern, Slot, compact_index, compact_rows,
+    empty_bindings, prefix_count,
 )
 from repro.core.rdf import PAD_ID, Vocab, make_triples
 
@@ -269,10 +272,14 @@ def _assert_same(got, want, what):
         assert bool(got.overflow) == bool(want[2]), what
 
 
-@pytest.mark.parametrize("seed", range(6))
+# seeds with a fixed shape: pair rows spanning several 128-wide blocks
+_JOIN_SHAPES = {6: (300, 200)}
+
+
+@pytest.mark.parametrize("seed", range(7))
 def test_joins_match_materialized_reference(seed):
     rng = np.random.default_rng(seed)
-    ca, cb = (int(x) for x in rng.integers(1, 24, 2))
+    ca, cb = _JOIN_SHAPES.get(seed) or (int(x) for x in rng.integers(1, 24, 2))
     a = _random_bindings(rng, ca, 4, 4)
     b = _random_bindings(rng, cb, 4, 4)
     for cap in (1, 7, ca * cb + ca + 2):
@@ -301,3 +308,42 @@ def test_prefix_count_is_cumsum(n):
     mask = np.random.default_rng(n).random(n) < 0.4
     np.testing.assert_array_equal(np.asarray(prefix_count(jnp.asarray(mask))),
                                   np.cumsum(mask, dtype=np.int32))
+
+
+# cquery1's sink: bind_cap x scan_cap pairs (join) and bind_cap^2 pairs plus
+# the unmatched left rows (optional join)
+_JOIN_PAIRS, _OPTIONAL_PAIRS = 4096 * 1024, 4096 * 4096 + 4096
+
+
+@pytest.mark.parametrize("n, density, out_cap", [
+    *((n, 0.4, rel) for n in (0, 1, 127, 128, 129, 70_000)
+      for rel in ("below", "at", "above")),
+    *((_JOIN_PAIRS, 0.02, rel) for rel in ("below", "at", "above")),
+    # the sink's own shapes, which take a middle level
+    (_JOIN_PAIRS, 2e-5, 4096), (_OPTIONAL_PAIRS, 2e-5, 4096),
+])
+def test_compact_index_matches_flatnonzero(n, density, out_cap):
+    mask = np.random.default_rng(n).random(n) < density
+    if n:
+        mask[n // 2] = True
+    count = int(mask.sum())
+    if isinstance(out_cap, str):
+        out_cap = max(count + {"below": -1, "at": 0, "above": 1}[out_cap], 0)
+    if out_cap == 4096:
+        assert out_cap * -(-n // 128) > pattern._DENSE_TOP
+    src, valid, overflow = compact_index(jnp.asarray(mask), out_cap)
+    src, valid = np.asarray(src), np.asarray(valid)
+    want = np.flatnonzero(mask)[:out_cap]
+    np.testing.assert_array_equal(valid, np.arange(out_cap) < len(want))
+    np.testing.assert_array_equal(src[:len(want)], want)
+    assert ((src >= 0) & (src < max(n, 1))).all()
+    assert bool(overflow) == (count > out_cap)
+
+
+@pytest.mark.parametrize("n", [_JOIN_PAIRS, _OPTIONAL_PAIRS])
+def test_compact_index_has_no_loop_at_stream_join_shapes(n):
+    """cquery1's join and optional join over 8 windows: a loop here is a
+    dependent gather per round over the whole pair table."""
+    search = jax.jit(jax.vmap(functools.partial(compact_index, out_cap=4096)))
+    hlo = search.lower(jax.ShapeDtypeStruct((8, n), jnp.bool_)).as_text()
+    assert "while" not in hlo
