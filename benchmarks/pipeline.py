@@ -91,7 +91,7 @@ def _stage_breakdown(world, base, q, chunks, query: str,
         for _ in range(passes):
             reg.run(chunks)
         stats = reg.last_stats
-        prefix = "stage" if mode == "pipelined" else "dscep.chunk"
+        prefix = "dscep.stage" if mode == "pipelined" else "dscep.chunk"
         breakdown[mode] = {
             "spans": stats["spans"],
             "operators": stats["operators"],
@@ -377,6 +377,7 @@ def run(iters: Optional[int] = None, smoke: bool = False,
                 "ExecutionConfig mode: monolithic vs single-program DAG vs "
                 "pipelined dataflow (up to channel_capacity chunks in "
                 "flight, sink-only blocking)",
+        "platform": jax.devices()[0].platform,
         "query": query,
         "kb_method": kb_method,
         "num_chunks": len(chunks),

@@ -120,6 +120,13 @@ def pop(ch: Channel) -> Tuple[Channel, Any, jax.Array]:
     return new, payload, ~empty
 
 
+def payload_bytes(tree: Any) -> int:
+    """Bytes of a payload pytree, from its leaves' static shapes and dtypes
+    (no device access)."""
+    return sum(int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
+               for leaf in jax.tree.leaves(tree))
+
+
 def occupancy(ch: Channel) -> jax.Array:
     """Current number of queued payloads (int32 scalar, device-resident)."""
     return ch.size

@@ -31,6 +31,13 @@ Structure:
   keeping ``depth`` chunks in flight (up to the channel capacity, default
   4).  All dispatch is async; only the sink output is ever blocked on.
 
+With a tracer attached the driver records ``dscep.stage`` (one stage's
+step, keyed by its operator or ``source``), ``dscep.transfer`` (a
+payload's put onto its consumer's device, keyed by its edge) and
+``dscep.drain`` (the sink's turn) spans; with metrics on it also counts
+each operator's channel bytes per chunk
+(:meth:`PipelinedRuntime.channel_traffic`).
+
 Results are bit-identical to :class:`DSCEPRuntime` and
 :class:`MonolithicRuntime` (tests/test_pipeline_runtime.py): the stages run
 the exact same window/engine/publish computations, merely cut at the channel
@@ -260,6 +267,9 @@ class PipelinedRuntime:
             n: {} for n in self.operators
         }
         self._op_step_stats = self._sink_step_stats = None
+        # metrics path: (inbound, outbound) payload bytes per chunk of each
+        # operator, from the payloads' static shapes
+        self._traffic: Dict[str, Tuple[int, int]] = {}
         if self._collect:
             self._op_step_stats = {
                 name: jax.jit(
@@ -317,6 +327,15 @@ class PipelinedRuntime:
         if self.placement is None:
             return tree
         return jax.device_put(tree, self.placement[op_name])
+
+    def _transfer(self, payload, op_name: str, edge: str, seq: int):
+        """A stage's payload on its consumer ``op_name``'s device: a
+        device-to-device copy where the producer sits on another one."""
+        if self.placement is None:
+            return payload
+        with span_or_null(self.tracer, "dscep.transfer", key=edge,
+                          edge=edge, seq=seq) as sp:
+            return sp.fence(self._on_device(payload, op_name))
 
     # -- host-side edge accounting (schedule facts, not device state) ----------
     def _edge_pushed(self, edge: str) -> None:
@@ -496,7 +515,7 @@ class PipelinedRuntime:
         inj = self._injector
         if inj is not None and inj.take("drop_payload", stage, seq):
             return
-        dev_payload = self._on_device(payload, self.final)
+        dev_payload = self._transfer(payload, self.final, edge, seq)
         dup = inj is not None and inj.take("duplicate_payload", stage, seq)
         for _ in range(2 if dup else 1):
             if stage == "source":
@@ -531,7 +550,8 @@ class PipelinedRuntime:
         src_edge = "source->%s" % self.final
         while self._src_q and self._edge_room(src_edge):
             seq, chunk = self._src_q.popleft()
-            with span_or_null(tr, "stage:source") as sp:
+            with span_or_null(tr, "dscep.stage", key="source",
+                              operator="source", seq=seq) as sp:
                 sink_payload, op_payload = self._run_stage(
                     "source", seq, lambda: self._win_step(chunk))
                 sp.fence(sink_payload)
@@ -548,16 +568,22 @@ class PipelinedRuntime:
             op = self.operators[name]
             while q and self._edge_room(edge):
                 seq, payload = q.popleft()
-                with span_or_null(tr, "stage:%s" % name) as sp:
+                payload = self._transfer(
+                    payload, name, "source->%s" % name, seq)
+                with span_or_null(tr, "dscep.stage", key=name,
+                                  operator=name, seq=seq) as sp:
                     def step(name=name, payload=payload, op=op):
                         if self._collect:
                             return self._op_step_stats[name](
-                                self._on_device(payload, name), op.kb, op.env)
+                                payload, op.kb, op.env)
                         return self._op_step[name](
-                            self._on_device(payload, name), op.kb, op.env), None
+                            payload, op.kb, op.env), None
                     publication, stats = self._run_stage(name, seq, step)
                     if stats is not None:
                         merge_stats(self._stats_acc[name], stats)
+                        self._traffic[name] = (
+                            channel.payload_bytes(payload),
+                            channel.payload_bytes(publication))
                     sp.fence(publication)
                 self._push_payload(name, edge, seq, publication)
 
@@ -646,23 +672,32 @@ class PipelinedRuntime:
             "capacities require a schedule-aware sink")
         seq = self._inflight_seqs[0] if self._inflight_seqs else -1
         final_op = self.operators[self.final]
-        with span_or_null(self.tracer, "stage:%s" % self.final) as sp:
-            def step():
-                if self._collect:
-                    return self._sink_step_stats(
+        tr = self.tracer
+        with span_or_null(tr, "dscep.drain", seq=seq):
+            with span_or_null(tr, "dscep.stage", key=self.final,
+                              operator=self.final, seq=seq) as sp:
+                def step():
+                    if self._collect:
+                        return self._sink_step_stats(
+                            self._agg_win_ch, self._out_ch, final_op.kb,
+                            final_op.env)
+                    return self._sink_step(
                         self._agg_win_ch, self._out_ch, final_op.kb,
-                        final_op.env)
-                return self._sink_step(
-                    self._agg_win_ch, self._out_ch, final_op.kb,
-                    final_op.env) + (None,)
-            res = self._run_stage(self.final, seq, step, retryable=False)
-            self._agg_win_ch, self._out_ch, out, overflow, stats = res
-            if stats is not None:
-                merge_stats(self._stats_acc[self.final], stats)
-            sp.fence(out)
-        for edge in self._edges():
-            self._edge_popped(edge)
-        self._accumulate_overflow(overflow)
+                        final_op.env) + (None,)
+                res = self._run_stage(self.final, seq, step, retryable=False)
+                self._agg_win_ch, self._out_ch, out, overflow, stats = res
+                if stats is not None:
+                    merge_stats(self._stats_acc[self.final], stats)
+                    # one slot of every inbound channel per chunk
+                    popped = [self._agg_win_ch, *self._out_ch.values()]
+                    self._traffic[self.final] = (
+                        sum(channel.payload_bytes(ch.slots) // ch.capacity
+                            for ch in popped),
+                        channel.payload_bytes(out))
+                sp.fence(out)
+            for edge in self._edges():
+                self._edge_popped(edge)
+            self._accumulate_overflow(overflow)
         self._last_overflow = overflow
         self._in_flight -= 1
         if self._inflight_seqs:
@@ -1036,6 +1071,30 @@ class PipelinedRuntime:
         """Finalized per-operator engine metric counters (empty unless the
         runtime was built with a metrics-collecting tracer)."""
         return {n: finalize_stats(a) for n, a in self._stats_acc.items() if a}
+
+    def channel_traffic(self) -> Dict[str, Dict[str, int]]:
+        """Per operator, once it ran on the metrics path: the bytes of the
+        payloads it receives and publishes per chunk (from their static
+        shapes), ``cross_device`` 1 where it is placed on another device
+        than the sink, and the high-water depth of its edge into the sink
+        (the window edge for the sink itself).  The window source runs
+        where a host chunk lands, the default device, which ``round_robin``
+        gives the sink: there the bytes that the operators with
+        ``cross_device`` 1 receive and publish are the bytes that cross
+        devices."""
+        sink_dev = self._final_device()
+        out: Dict[str, Dict[str, int]] = {}
+        for name, (in_b, out_b) in self._traffic.items():
+            src = "source" if name == self.final else name
+            out[name] = {
+                "in_bytes_per_chunk": in_b,
+                "out_bytes_per_chunk": out_b,
+                "cross_device": int(self.placement is not None
+                                    and self.placement[name] != sink_dev),
+                "depth_hw": self._edge_stats[
+                    "%s->%s" % (src, self.final)]["depth_hw"],
+            }
+        return out
 
     @property
     def degraded(self) -> bool:

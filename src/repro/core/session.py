@@ -348,22 +348,29 @@ class RegisteredQuery:
               "overflow_totals": {op: windows clipped, ...},
               "channels": {edge: {...}, ...},      # {} outside pipelined
               "operators": {op: {"counters": ..., "caps": ...,
-                                 "saturation": ...}, ...},
+                                 "saturation": ...,
+                                 "channel": {...}}, ...},  # pipelined
               "spans": {path: {"count", "first_s", "steady": {...}}, ...},
               "recovery": {"enabled", "injected", "retries", ...},
               "degraded": bool,
             }
 
         ``operators`` and ``spans`` fill in only when the session ran with
-        ``ExecutionConfig(trace=...)`` enabled; ``recovery`` carries live
+        ``ExecutionConfig(trace=...)`` enabled; in pipelined mode each
+        operator's entry then also holds its ``channel`` traffic
+        (``PipelinedRuntime.channel_traffic``); ``recovery`` carries live
         counters only under pipelined ``faults=``/``recovery=``; the rest
         is always live.
         """
         ops: Dict[str, Any] = {}
+        traffic = self._runtime.channel_traffic() \
+            if self.mode == "pipelined" else {}
         for name, counters in self._runtime.op_metrics().items():
             op = self.operators.get(name)
             caps = plan_caps(op.plan) if op is not None else {}
             ops[name] = attach_saturation(counters, caps)
+            if name in traffic:
+                ops[name]["channel"] = traffic[name]
         return {
             "query": self.query.name,
             "mode": self.mode,

@@ -345,7 +345,7 @@ def _report_trace(reg, args):
     stats = reg.last_stats
     if stats["spans"]:
         print(format_stage_table(stats["spans"]))
-        prefix = "stage" if args.mode == "pipelined" else "dscep.chunk"
+        prefix = "dscep.stage" if args.mode == "pipelined" else "dscep.chunk"
         print("[dscep] bottleneck stage: "
               f"{bottleneck_stage(stats['spans'], prefix=prefix)}")
     if stats["operators"]:

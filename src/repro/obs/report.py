@@ -41,7 +41,7 @@ def bottleneck_stage(span_stats: Mapping[str, Dict[str, Any]],
                      prefix: Optional[str] = None) -> Optional[str]:
     """The span path with the largest steady-state total time.
 
-    ``prefix`` restricts candidates (e.g. ``"stage"`` for the pipelined
+    ``prefix`` restricts candidates (e.g. ``"dscep.stage"`` for the pipelined
     runtime's per-stage spans, skipping the enclosing chunk span).  Paths
     without steady samples (only a compile-inclusive first call) compete on
     that first sample so a single-pass trace still answers.

@@ -18,6 +18,17 @@ Latency attribution in a JAX pipeline has two classic traps:
 
 Spans nest: a span opened while another is active records under the path
 ``outer/inner``, giving per-stage attribution inside a chunk-level span.
+A span given a ``key`` records under ``name[key]``, so spans of one name
+(one per pipeline stage, say) keep apart in the statistics while the
+profiler sees the one name.
+
+The program's host spans are named ``dscep.*``: ``dscep.register`` and
+its steps, ``dscep.chunk`` (``/dscep.dispatch``, ``/dscep.account``) in
+the one-program runtimes, and in the pipelined runtime ``dscep.stage``
+(one stage's step, keyed by its operator or ``source``),
+``dscep.transfer`` (a payload's copy to another stage's device, keyed by
+its edge) and ``dscep.drain`` (the sink's turn: its step and the
+overflow accounting).
 
 Every recorded span is also a :class:`jax.profiler.TraceAnnotation`, so
 when a ``jax.profiler`` trace runs, the program's spans sit on the same
@@ -135,12 +146,13 @@ def _null_span():
     yield _NULL_SPAN
 
 
-def span_or_null(tracer: Optional["Tracer"], name: str, **meta):
+def span_or_null(tracer: Optional["Tracer"], name: str,
+                 key: Optional[str] = None, **meta):
     """Span on ``tracer`` when present, else a no-op span context — lets
     runtime call sites stay branch-free whether or not tracing is wired."""
     if tracer is None:
         return _null_span()
-    return tracer.span(name, **meta)
+    return tracer.span(name, key=key, **meta)
 
 
 class Tracer:
@@ -162,8 +174,10 @@ class Tracer:
         return self.config.spans
 
     # -- recording -----------------------------------------------------------
-    def span(self, name: str, **meta):
+    def span(self, name: str, key: Optional[str] = None, **meta):
         """Context manager for one timed span; nests under the active span.
+        The profiler annotation is ``name`` with ``meta``; the statistics
+        keep the span under ``name[key]`` when a ``key`` is given.
 
         Usage::
 
@@ -173,12 +187,13 @@ class Tracer:
         """
         if not self.config.spans:
             return _null_span()
-        return self._span_cm(name, meta)
+        return self._span_cm(name, key, meta)
 
     @contextlib.contextmanager
-    def _span_cm(self, name: str, meta: Dict[str, Any]):
-        path = "/".join(self._stack + [name])
-        self._stack.append(name)
+    def _span_cm(self, name: str, key: Optional[str], meta: Dict[str, Any]):
+        seg = name if key is None else "%s[%s]" % (name, key)
+        path = "/".join(self._stack + [seg])
+        self._stack.append(seg)
         handle = _SpanHandle()
         ann = jax.profiler.TraceAnnotation(name, **meta)
         ann.__enter__()

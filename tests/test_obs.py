@@ -292,8 +292,10 @@ def test_traced_metrics_agree_across_decomposed_modes(oworld):
         reg.run(oworld.chunks)
         stats = reg.last_stats
         assert stats["operators"], mode
+        # pipelined mode adds each operator's channel traffic
+        extra = {"channel"} if mode == "pipelined" else set()
         for entry in stats["operators"].values():
-            assert {"counters", "caps", "saturation"} == set(entry)
+            assert {"counters", "caps", "saturation"} | extra == set(entry)
         metrics[mode] = {
             op: entry["counters"]
             for op, entry in stats["operators"].items()
@@ -324,16 +326,117 @@ def test_pipelined_stage_spans_cover_every_operator(oworld):
     reg.run(oworld.chunks)
     reg.run(oworld.chunks)                 # second pass fills steady samples
     spans = reg.last_stats["spans"]
-    stages = {p.split("/")[-1] for p in spans
-              if p.split("/")[-1].startswith("stage:")}
-    expected = {"stage:source"} | {
-        "stage:%s" % name for name in reg.operators}
-    assert stages == expected
-    for path, s in spans.items():
-        if path.split("/")[-1].startswith("stage:"):
-            assert s["count"] > 0 and s["steady"]["count"] > 0, path
-    assert bottleneck_stage(spans, prefix="stage") in {
-        p for p in spans if p.split("/")[-1].startswith("stage:")}
+    rt = reg.runtime
+
+    def named(prefix):
+        return {p: s for p, s in spans.items()
+                if p.split("/")[-1].startswith(prefix + "[")}
+
+    stages = named("dscep.stage")
+    assert {p.split("/")[-1] for p in stages} == {
+        "dscep.stage[%s]" % name for name in ["source", *reg.operators]}
+    for path, s in stages.items():
+        assert s["count"] > 0 and s["steady"]["count"] > 0, path
+        # one profiler name, the operator as metadata
+        assert "dscep.stage[%s]" % s["meta"]["operator"] == \
+            path.split("/")[-1]
+    # the sink's step runs inside its drain; the others stand alone
+    assert "dscep.drain/dscep.stage[%s]" % rt.final in stages
+    assert spans["dscep.drain"]["count"] == 2 * len(oworld.chunks)
+    transfers = {p.split("/")[-1] for p in named("dscep.transfer")}
+    assert transfers == {"dscep.transfer[%s]" % e for e in
+                         rt._edges() + ["source->%s" % n
+                                        for n in rt.upstream]}
+    assert bottleneck_stage(spans, prefix="dscep.stage") in stages
+
+
+def test_tracer_key_splits_stats_not_the_span_name(monkeypatch):
+    seen = []
+
+    class Ann:
+        def __init__(self, name, **meta):
+            seen.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    tr = Tracer(TraceConfig(fence=False))
+    for op in ("a", "b", "a"):
+        with span_or_null(tr, "dscep.stage", key=op, operator=op):
+            pass
+    stats = tr.stats()
+    assert set(stats) == {"dscep.stage[a]", "dscep.stage[b]"}
+    assert stats["dscep.stage[a]"]["count"] == 2
+    assert stats["dscep.stage[b]"]["meta"] == {"operator": "b"}
+    assert [n for n, _ in seen] == ["dscep.stage"] * 3
+    assert seen[1][1] == {"operator": "b"}
+
+
+def test_pipelined_channel_counters_are_payload_bytes(oworld):
+    """On the metrics path every operator's entry holds its channel
+    traffic: the bytes of the payloads it receives and publishes per chunk,
+    computed here from the configuration's shapes."""
+    reg = oworld.session(CFG.replace(
+        mode="pipelined", trace=TraceConfig(spans=False, metrics=True,
+                                            fence=False))).register(
+        PQ.CQUERY1_RQ)
+    reg.run(oworld.chunks)
+    rt = reg.runtime
+    ops = reg.last_stats["operators"]
+    assert set(ops) == set(reg.operators)
+    W, C = CFG.max_windows, CFG.window_capacity
+    row = 5 * 4 + 1                        # s, p, o, ts, graph u32; valid
+    windows = W * C * row + W              # + window_valid
+    pubs = {}
+    for name in rt.upstream:
+        spec = rt._split.pub[name]
+        # table u32[W, rows, k], its row mask, and the overflow flags
+        pubs[name] = W * spec.rows_cap * (4 * len(spec.cols) + 1) + W
+        assert ops[name]["channel"] == {
+            "in_bytes_per_chunk": windows, "out_bytes_per_chunk": pubs[name],
+            "cross_device": 0,             # one CPU device
+            "depth_hw": reg.channel_stats()[
+                "%s->%s" % (name, rt.final)]["depth_hw"]}
+    sink = ops[rt.final]["channel"]
+    assert sink["in_bytes_per_chunk"] == windows + sum(pubs.values())
+    assert sink["out_bytes_per_chunk"] == CFG.out_stream_cap * row
+    assert sink["cross_device"] == 0 and sink["depth_hw"] >= 2
+    assert "saturation" in ops[rt.final]
+
+
+def test_pipelined_off_path_has_no_spans_counters_or_new_programs(oworld):
+    """Tracing off: no span, no channel counter, and every stage traces
+    the program a spans-and-metrics build traces on its plain path."""
+    off = oworld.session(CFG.replace(mode="pipelined")).register(
+        PQ.CQUERY1_RQ)
+    on = oworld.session(CFG.replace(mode="pipelined", trace=True)).register(
+        PQ.CQUERY1_RQ)
+    off.run(oworld.chunks)
+    on.run(oworld.chunks)
+    stats = off.last_stats
+    assert stats["spans"] == {} and stats["operators"] == {}
+    assert off.runtime._traffic == {}
+    assert all("channel" in e for e in on.last_stats["operators"].values())
+    a, b = off.runtime, on.runtime
+    chunk = oworld.chunks[0]
+
+    def jp(fn, *args):
+        return str(jax.make_jaxpr(fn)(*args))
+
+    assert jp(a._windows_impl, chunk) == jp(b._windows_impl, chunk)
+    _, shape = jax.eval_shape(a._windows_impl, chunk)
+    payload = jax.tree.map(lambda x: jax.numpy.zeros(x.shape, x.dtype), shape)
+    for name in a.upstream:
+        oa, ob = a.operators[name], b.operators[name]
+        assert jp(a._op_step[name], payload, oa.kb, oa.env) == \
+            jp(b._op_step[name], payload, ob.kb, ob.env), name
+    fa, fb = a.operators[a.final], b.operators[b.final]
+    assert jp(a._sink_step, a._agg_win_ch, a._out_ch, fa.kb, fa.env) == \
+        jp(b._sink_step, b._agg_win_ch, b._out_ch, fb.kb, fb.env)
 
 
 # --------------------------------------------------------------------------
