@@ -6,8 +6,15 @@ that their sum of triples is a maximum of 1000 RDF triples".  We reproduce
 exactly that packing, generalized to sliding count windows
 (``[RANGE TRIPLES n STEP m]``), plus time-based tumbling/sliding windows.
 
-Sliding count windows factor through *slides*: the stream is greedily packed
-graph-by-graph into slides of ``m`` triples, and window ``w`` is the
+The packing is next-fit over graph sizes: a unit (window or slide) takes
+graphs in stream order until the next one would overflow it.  It is computed
+from prefix sums of the sizes, one step per *unit* rather than per graph: the
+next unit starts at the first graph whose inclusive prefix passes the current
+unit's start prefix plus the capacity, so a chunk's packing costs
+``max_units`` dense compare-and-count passes over its rows.
+
+Sliding count windows factor through *slides*: the stream is packed into
+slides of ``m`` triples, and window ``w`` is the
 concatenation of slides ``w .. w + R - 1`` with ``R = ceil(n / m)``.  The
 slide is the packing unit — a graph never splits across slides, and a graph
 larger than ``m`` is truncated to ``m`` in a slide of its own, the same
@@ -96,14 +103,18 @@ def _segment_first(values: jax.Array, seg_starts: jax.Array) -> jax.Array:
 def _pack_rows(
     stream: TripleBatch, capacity: int, max_units: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Greedy graph-preserving packing of the stream into capacity-bounded
-    units (windows or slides).
+    """Next-fit graph-preserving packing of the stream into capacity-bounded
+    units (windows or slides), stepped once per unit by prefix sums.
 
     The stream must be timestamp-ordered with invalid rows at the tail (the
     merge stage guarantees this).  Graph events are contiguous runs of equal
     ``graph`` id; a graph moves to the next unit when it would overflow the
     current one.  Graphs larger than ``capacity`` get a unit of their own
-    (truncated to capacity, matching a bounded-buffer engine).
+    (truncated to capacity, matching a bounded-buffer engine).  With sizes
+    clipped to ``capacity`` and their exclusive prefix ``exc``, the unit
+    starting at graph ``s`` ends before the first graph ``t`` with
+    ``exc[t + 1] > exc[s] + capacity``; a loop of ``max_units`` trips finds
+    the unit starts, and each graph's offset is ``exc[g] - exc[start]``.
 
     Returns ``(unit, col, ok)`` per row: unit ordinal, column within the
     unit, and whether the row landed (valid, within ``max_units``, within
@@ -127,28 +138,26 @@ def _pack_rows(
         valid.astype(jnp.int32), jnp.where(graph_idx < 0, num_graphs - 1, graph_idx),
         num_segments=num_graphs,
     )
-    graph_live = sizes > 0
 
-    # --- greedy packing of graph sizes into units (scan over graphs)
-    def pack(carry, size_live):
-        fill, wid = carry
-        size, live = size_live
-        size_c = jnp.minimum(size, capacity)
-        overflow = fill + size_c > capacity
-        new_wid = jnp.where(overflow, wid + 1, wid)
-        new_fill = jnp.where(overflow, size_c, fill + size_c)
-        new_wid_out = jnp.where(live, new_wid, wid)
-        carry = (
-            jnp.where(live, new_fill, fill),
-            new_wid_out,
-        )
-        # offset of this graph inside its unit
-        offset = jnp.where(overflow, 0, fill)
-        return carry, (new_wid_out, offset)
+    # --- next-fit by prefix sums, one step per unit: the unit starting at
+    # graph s ends where the inclusive prefix passes exc[s] + capacity.
+    # Every clipped size is <= capacity, so each unit takes >= 1 graph
+    # until the starts reach n, where they stay.
+    size_c = jnp.minimum(sizes, capacity)
+    inc = jnp.cumsum(size_c)
+    exc = jnp.concatenate([jnp.zeros((1,), inc.dtype), inc])          # [n + 1]
 
-    (_, _), (graph_wid, graph_off) = jax.lax.scan(
-        pack, (jnp.int32(0), jnp.int32(0)), (sizes, graph_live)
-    )
+    def next_unit(u, starts):
+        nxt = jnp.sum(inc <= exc[starts[u]] + capacity, dtype=jnp.int32)
+        return starts.at[u + 1].set(nxt)
+
+    starts = jax.lax.fori_loop(
+        0, max_units, next_unit, jnp.zeros((max_units + 1,), jnp.int32))
+    # unit of a graph = starts 1..max_units at or below it; graphs past the
+    # last start read max_units, which ``ok`` drops
+    marks = jnp.zeros((n + 1,), jnp.int32).at[starts[1:]].add(1)
+    graph_wid = jnp.cumsum(marks[:n])
+    graph_off = exc[:n] - exc[starts[graph_wid]]
 
     # position of a row within its graph = row index - index of graph start
     graph_start = jnp.where(new_graph, jnp.arange(n), 0)
