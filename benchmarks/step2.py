@@ -62,38 +62,27 @@ def run(world: BenchWorld = None, iters: int = 5) -> dict:
         t_mono = time_fn(lambda c: mono.process_chunk(c)[0], chunk, iters=iters)
 
         # -- Table 3: per-operator steady-state times (operators as deployed
-        #    units on separate machines — each timed as its own jitted program)
+        #    units on separate machines — each stage of the chunk step timed
+        #    as its own jitted program)
         import jax
-        from repro.core.stream import merge_streams
-        from repro.core.window import count_windows
 
-        merged = merge_streams([chunk])
-        windows = count_windows(merged, cfg.window_capacity, cfg.max_windows)
+        step = split.step
+        sink_in, op_in = jax.jit(step.source)(chunk)
         op_times = {}
-        upstream = {}
-        for name, op in split.operators.items():
-            if name == dag.final:
-                continue
-            fn = jax.jit(lambda w, kb, env, op=op: op.process_windows(w, kb, env))
-            op_times[name] = time_fn(fn, windows, op.kb, op.env, iters=iters)
-            upstream[name] = op.process_windows(windows, op.kb, op.env)[0]
+        payloads = {}
+        for name in step.upstream_names:
+            op = split.operators[name]
+            fn = jax.jit(lambda x, kb, env, name=name:
+                         step.upstream(name, x, kb, env)[0])
+            op_times[name] = time_fn(fn, op_in, op.kb, op.env, iters=iters)
+            payloads[name] = fn(op_in, op.kb, op.env)
 
-        # aggregation operator on the window-aligned augmented stream
-        import jax.numpy as jnp
-        from repro.core.rdf import TripleBatch
-        from repro.core.window import Windows
-
+        # aggregation operator on the windows and the upstream publications
         final_op = split.operators[dag.final]
-        parts = [windows.triples] + [
-            upstream[src] for src in dag.subqueries[dag.final].inputs
-            if src != "stream"
-        ]
-        aug = TripleBatch(*(jnp.concatenate(c, axis=-1) for c in zip(*parts)))
-        aug_w = Windows(aug, windows.window_valid)
         fn_agg = jax.jit(
-            lambda w, kb, env: final_op.process_windows(w, kb, env))
-        op_times[dag.final] = time_fn(fn_agg, aug_w, final_op.kb, final_op.env,
-                                      iters=iters)
+            lambda s, p, kb, env: step.sink(s, p, kb, env)[0])
+        op_times[dag.final] = time_fn(fn_agg, sink_in, payloads, final_op.kb,
+                                      final_op.env, iters=iters)
 
         # -- fused whole-DAG single program (beyond-paper deployment)
         t_fused = time_fn(lambda c: split.process_chunk(c)[0], chunk, iters=iters)

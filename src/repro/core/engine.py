@@ -175,8 +175,8 @@ def _binding_table(
     span columns onto the state's span columns at ``width - num_span``.
     """
     assert tables is not None and step.source in tables, (
-        "BindingJoin on %r but no table supplied — split-sink runners must "
-        "pass the upstream tables" % step.source)
+        "BindingJoin on %r but no table supplied — the sink's runner must "
+        "be passed the upstream tables" % step.source)
     tcols, tvalid = tables[step.source]
     k = len(step.cols)
     out = jnp.zeros((tcols.shape[0], width), jnp.uint32)
@@ -299,10 +299,12 @@ def finalize_bindings(
 
 
 def run_plan(
-    plan: Plan, window: TripleBatch, kb: Optional[KnowledgeBase], env: Env,
-    graph_base: jax.Array | int = 0, stats: Stats = None,
+    plan: Plan, window: TripleBatch, wid: jax.Array,
+    kb: Optional[KnowledgeBase], env: Env, stats: Stats = None,
+    tables: Tables = None,
 ) -> Tuple[TripleBatch, Bindings, jax.Array]:
-    """Execute ``plan`` on one window.
+    """Execute ``plan`` on window ``wid`` of a batch (its output graph ids
+    start at ``wid * bind_cap``).
 
     Returns (constructed stream, final bindings, overflow flag).  Before
     CONSTRUCT the bindings are projected onto the template variables,
@@ -315,15 +317,16 @@ def run_plan(
     bit-identical for every query, not just the paper's.
     """
     cur = universe_bindings(plan.bind_cap, plan.num_vars)
-    cur = run_steps(plan, cur, plan.steps, window, kb, env, stats)
+    cur = run_steps(plan, cur, plan.steps, window, kb, env, stats, tables)
     ts = jnp.max(jnp.where(window.valid, window.ts, 0))
-    out, ovf = finalize_bindings(plan, cur, ts, graph_base, stats)
+    out, ovf = finalize_bindings(
+        plan, cur, ts, wid.astype(jnp.uint32) * plan.bind_cap, stats)
     return out, cur, ovf
 
 
 def run_plan_windows(
     plan: Plan, windows: Windows, kb: Optional[KnowledgeBase], env: Env,
-    with_stats: bool = False,
+    with_stats: bool = False, tables: Tables = None,
 ):
     """vmap the plan over a window batch.
 
@@ -333,22 +336,29 @@ def run_plan_windows(
     scalars (per-window gauges reduced per the hw_/n_ convention, see
     repro.obs.metrics) — the stats-off call traces the exact same program
     as before instrumentation.
+
+    ``tables`` (the split aggregation sink) are per-window upstream binding
+    tables, ``[W, rows, k]`` / ``[W, rows]`` leaves batched alongside the
+    windows, which the rewritten sink plan's BindingJoin steps consume.
+    The finalize tail, and so the published bits, equal the unsplit
+    path's: upstream publication triples carry their window's max
+    timestamp, so the raw window's ts equals the augmented one.
     """
     w = windows.num_windows
+    names = tuple(tables or ())
 
-    def one(window, wid, wvalid):
+    def one(window, wid, wvalid, table_vals):
         stats: Stats = {} if with_stats else None
-        out, _, ovf = run_plan(
-            plan, window, kb, env,
-            graph_base=wid.astype(jnp.uint32) * plan.bind_cap, stats=stats,
-        )
+        out, _, ovf = run_plan(plan, window, wid, kb, env, stats,
+                               dict(zip(names, table_vals)))
         out = out._replace(valid=out.valid & wvalid)
         if with_stats:
             return out, ovf, stats
         return out, ovf
 
-    res = jax.vmap(one, in_axes=(0, 0, 0))(
-        windows.triples, jnp.arange(w), windows.window_valid
+    res = jax.vmap(one)(
+        windows.triples, jnp.arange(w), windows.window_valid,
+        tuple(tables[n] for n in names),
     )
     if not with_stats:
         return res
@@ -458,6 +468,9 @@ def run_plan_slides(
     the whole chunk where recompute gets them per window; overflow trips
     earlier here (size caps to the *sum* of window populations), which the
     flag reports exactly as usual.
+
+    ``tables`` (the split sink on the delta path) are chunk-level
+    span-tagged upstream tables for the plan's BindingJoin steps.
     """
     r = slides_per_window
     stats: Stats = {} if with_stats else None
@@ -495,7 +508,7 @@ def run_plan_slides(
 
 
 # --------------------------------------------------------------------------
-# split aggregation sink: upstream table producers + sink runners
+# split aggregation sink: upstream table producers
 # --------------------------------------------------------------------------
 #
 # The binding-graph protocol (planner.decompose) ships upstream results as
@@ -510,6 +523,8 @@ def run_plan_slides(
 # Output bits are unchanged: the published stream is a function of the
 # binding *set* (finalize_bindings dedups and canonically orders), and the
 # table rows are exactly the rows the decode scans would have reconstructed.
+# The sink itself runs through run_plan_windows / run_plan_slides with the
+# tables passed as ``tables=``.
 
 def _clip_table(
     emit: Bindings, pub_cols: Tuple[int, ...], rows_cap: int,
@@ -606,60 +621,3 @@ def run_plan_slide_tables(
         stat_max(stats, "hw_out", jnp.sum(valid.astype(jnp.int32)))
         return (cols, valid), ovf, stats
     return (cols, valid), ovf
-
-
-def run_sink_windows(
-    plan: Plan, windows: Windows,
-    tables: Dict[str, Tuple[jax.Array, jax.Array]],
-    kb: Optional[KnowledgeBase], env: Env, with_stats: bool = False,
-):
-    """Split-sink twin of :func:`run_plan_windows`: vmaps the rewritten sink
-    plan over the RAW windows with the per-window upstream tables as extra
-    batched operands.  ``tables[name]`` leaves are ``[W, rows, k]`` /
-    ``[W, rows]``.  The finalize tail (and therefore the published bits)
-    is identical to the unsplit path — upstream publication triples carry
-    their window's max timestamp, so the raw-window ts equals the augmented
-    one.
-    """
-    w = windows.num_windows
-    names = tuple(tables)
-
-    def one(window, wid, wvalid, table_vals):
-        stats: Stats = {} if with_stats else None
-        tdict = dict(zip(names, table_vals))
-        cur = universe_bindings(plan.bind_cap, plan.num_vars)
-        cur = run_steps(plan, cur, plan.steps, window, kb, env, stats, tdict)
-        ts = jnp.max(jnp.where(window.valid, window.ts, 0))
-        out, ovf = finalize_bindings(
-            plan, cur, ts, wid.astype(jnp.uint32) * plan.bind_cap, stats)
-        out = out._replace(valid=out.valid & wvalid)
-        if with_stats:
-            return out, ovf, stats
-        return out, ovf
-
-    res = jax.vmap(one)(
-        windows.triples, jnp.arange(w), windows.window_valid,
-        tuple(tables[n] for n in names),
-    )
-    if not with_stats:
-        return res
-    out, ovf, per_window = res
-    stats = reduce_stats(per_window)
-    stat_add(stats, "n_windows",
-             jnp.sum(windows.window_valid.astype(jnp.int32)))
-    return out, ovf, stats
-
-
-def run_sink_slides(
-    plan: Plan, view: SlideView,
-    tables: Dict[str, Tuple[jax.Array, jax.Array]],
-    slides_per_window: int, max_windows: int,
-    kb: Optional[KnowledgeBase], env: Env, with_stats: bool = False,
-):
-    """Split-sink twin of :func:`run_plan_slides`: the rewritten sink plan's
-    delta pass over the merged chunk, joining chunk-level span-tagged
-    upstream tables, then the standard per-window interval-select +
-    finalize.  Shares :func:`run_plan_slides` outright so the set-to-stream
-    tail can never diverge from the recompute path."""
-    return run_plan_slides(plan, view, slides_per_window, max_windows,
-                           kb, env, with_stats, tables=tables)

@@ -20,18 +20,12 @@ import jax.numpy as jnp
 
 from repro.obs.trace import in_layer
 
-from .engine import (
-    Plan, run_plan_slide_tables, run_plan_slides, run_plan_window_tables,
-    run_plan_windows, run_sink_slides, run_sink_windows,
-)
-from .kb import KnowledgeBase, pad_to
+from .engine import Plan, run_plan_slides, run_plan_windows
+from .kb import KnowledgeBase
 from .planner import plan_supports_delta
 from .rdf import TripleBatch
 from .stream import merge_streams
-from .window import (
-    SlideView, Windows, count_slides, count_windows, window_slides,
-    windows_from_slides,
-)
+from .window import count_slides, count_windows, window_slides
 
 
 @in_layer("publish")
@@ -93,10 +87,12 @@ class SCEPOperator:
         # returns a flat dict of chunk-scalar engine metrics.
         cfg = self.config
         merged = merge_streams(chunks)                       # Aggregator: merge+order
-        if cfg.incremental:
+        if cfg.incremental and plan_supports_delta(self.plan):
             view = count_slides(
                 merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-            res = self._engine_slides(view, kb, env, with_stats)
+            _, r = window_slides(cfg.window_capacity, cfg.window_step)
+            res = run_plan_slides(self.plan, view, r, cfg.max_windows, kb,
+                                  env, with_stats)
         else:
             windows = count_windows(
                 merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
@@ -106,103 +102,6 @@ class SCEPOperator:
             return self._publish(out_w), overflow, stats
         out_w, overflow = res
         return self._publish(out_w), overflow
-
-    def process_windows(
-        self, windows: Windows, kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Window-aligned engine step: ``[W, C]`` in -> ``[W, out_cap]`` out.
-
-        Used by the DAG runtime so downstream operators see upstream results
-        in the *same* window (the paper pipelines whole windows between
-        operators; re-windowing intermediates would break result equivalence).
-        """
-        return run_plan_windows(
-            self.plan, windows, kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
-
-    def process_slides(
-        self, view: SlideView, kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Slide-aligned engine step for incremental mode: evaluates the
-        chunk once with delta state when the plan is delta-safe, else
-        materializes the overlapping windows and recomputes per window —
-        either way the ``[W, out_cap]`` output is bit-identical."""
-        return self._engine_slides(
-            view, kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
-
-    def _engine_slides(
-        self, view: SlideView, kb: Optional[KnowledgeBase],
-        env: Dict[str, jax.Array], with_stats: bool = False,
-    ):
-        cfg = self.config
-        _, r = window_slides(cfg.window_capacity, cfg.window_step)
-        if plan_supports_delta(self.plan):
-            return run_plan_slides(
-                self.plan, view, r, cfg.max_windows, kb, env, with_stats)
-        windows = windows_from_slides(
-            view, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        return run_plan_windows(self.plan, windows, kb, env, with_stats)
-
-    # -- split-sink surfaces (see engine's split-sink section) ----------------
-    def process_window_tables(
-        self, windows: Windows, pub_cols: Tuple[int, ...], rows_cap: int,
-        kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Table-producing twin of :meth:`process_windows`: the operator's
-        final binding table per window instead of its triple publication —
-        what the split aggregation sink joins directly."""
-        return run_plan_window_tables(
-            self.plan, windows, pub_cols, rows_cap,
-            kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
-
-    def process_slide_tables(
-        self, view: SlideView, pub_cols: Tuple[int, ...], rows_cap: int,
-        kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Incremental table producer: one chunk-level span-tagged table
-        (requires a delta-safe plan — the split-sink builder gates on it)."""
-        cfg = self.config
-        _, r = window_slides(cfg.window_capacity, cfg.window_step)
-        return run_plan_slide_tables(
-            self.plan, view, pub_cols, rows_cap, r,
-            kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
-
-    def process_sink_windows(
-        self, windows: Windows, tables, kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Split-sink step over RAW windows + per-window upstream tables
-        (``self.plan`` must be the rewritten plan with BindingJoin steps)."""
-        return run_sink_windows(
-            self.plan, windows, tables,
-            kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
-
-    def process_sink_slides(
-        self, view: SlideView, tables, kb: Optional[KnowledgeBase] = None,
-        env: Optional[Dict[str, jax.Array]] = None, with_stats: bool = False,
-    ):
-        """Split-sink step on the delta path: the sink's own chain runs once
-        per chunk over span-tagged upstream tables, finalizing per window."""
-        cfg = self.config
-        _, r = window_slides(cfg.window_capacity, cfg.window_step)
-        return run_sink_slides(
-            self.plan, view, tables, r, cfg.max_windows,
-            kb if kb is not None else self.kb,
-            env if env is not None else self.env, with_stats,
-        )
 
     def _publish(self, out_w: TripleBatch) -> TripleBatch:
         """Publisher: flatten [W, cap] window outputs into one ordered chunk."""

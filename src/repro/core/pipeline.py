@@ -39,8 +39,9 @@ each operator's channel bytes per chunk
 (:meth:`PipelinedRuntime.channel_traffic`).
 
 Results are bit-identical to :class:`DSCEPRuntime` and
-:class:`MonolithicRuntime` (tests/test_pipeline_runtime.py): the stages run
-the exact same window/engine/publish computations, merely cut at the channel
+:class:`MonolithicRuntime` (tests/test_pipeline_runtime.py): the stages are
+:class:`~repro.core.runtime.DagStep`'s source, upstream and sink — the
+chunk step the single-program runtime jits whole — merely cut at the channel
 boundaries instead of fused into one program.
 """
 from __future__ import annotations
@@ -61,7 +62,7 @@ from .channel import Channel
 from .faults import FaultInjector, FaultPlan, InjectedCrash, corrupt_batch, validate_chunk
 from .kb import KnowledgeBase
 from .planner import OperatorDAG
-from .rdf import TripleBatch, Vocab, empty_triples
+from .rdf import TripleBatch, Vocab
 from .recovery import (
     ChannelDesyncError, Checkpoint, ChunkRejectedError, PipelineStalledError,
     RecoveryConfig, RecoveryExhaustedError, StageTimeoutError,
@@ -69,31 +70,8 @@ from .recovery import (
     snapshot_tree, tree_bytes, wait_until_ready,
 )
 from .runtime import (
-    RuntimeConfig, _warn_legacy_constructor, augment_windows, build_operators,
-    prepare_split_sink,
+    DagStep, RuntimeConfig, _warn_legacy_constructor, build_operators,
 )
-from .stream import merge_streams
-from .window import (
-    SlideView, Windows, count_slides, window_slides, windows_from_slides,
-)
-
-
-def _zeros_windows(num_windows: int, capacity: int) -> Windows:
-    """A shape/dtype example for sizing source→operator channel slots."""
-    z = jax.tree.map(
-        lambda col: jnp.zeros((num_windows,) + col.shape, col.dtype),
-        empty_triples(capacity),
-    )
-    return Windows(z, jnp.zeros((num_windows,), bool))
-
-
-def _zeros_publication(num_windows: int, out_cap: int) -> Tuple[TripleBatch, jax.Array]:
-    """Shape/dtype example for an operator→aggregator channel slot."""
-    tb = jax.tree.map(
-        lambda col: jnp.zeros((num_windows,) + col.shape, col.dtype),
-        empty_triples(out_cap),
-    )
-    return tb, jnp.zeros((num_windows,), bool)
 
 
 class PipelinedRuntime:
@@ -152,12 +130,6 @@ class PipelinedRuntime:
         self.channel_capacity = channel_capacity
         self.operators = build_operators(dag, kb, cfg, tracer)
         self.final = dag.final
-        # upstream operators in DAG insertion order — the same order
-        # DSCEPRuntime._dag_impl iterates (augment_windows keys by name, so
-        # results do not depend on this order; the channels merely pair up)
-        self.upstream: List[str] = [
-            n for n in dag.subqueries if n != self.final
-        ]
         self.placement = dict(placement) if placement else None
         if self.placement is not None:
             missing = set(self.operators) - set(self.placement)
@@ -171,63 +143,18 @@ class PipelinedRuntime:
                     op.kb = jax.device_put(op.kb, dev)
                 op.env = jax.device_put(op.env, dev)
 
-        # --- split aggregation sink: upstream stages publish binding
-        # *tables*, the sink joins them directly (None -> augmented path).
-        # Swap the sink operator's plan so EXPLAIN/last_stats report the
-        # plan that actually runs.
+        # --- the chunk step, cut into stages below; it decides what the
+        # channels carry (windows or slide view, triples or tables)
         with span_or_null(tracer, "dscep.split_sink"):
-            self._split = prepare_split_sink(dag, self.operators, cfg, mesh)
-        if self._split is not None:
-            self.operators[self.final].plan = self._split.plan
+            self.step = DagStep(dag, self.operators, cfg)
+        self._split = self.step.split
+        self.upstream: List[str] = self.step.upstream_names
 
         # --- per-edge channels (allocated on the consumer's device).  Only
         # the aggregator's inbound edges buffer across ticks; upstream
         # operators consume windows the tick they are produced, so they get
         # a direct transfer instead of a pass-through queue.
-        # physical window width is R * slide_capacity (== window_capacity
-        # when tumbling, rounded up for a non-dividing STEP)
-        slide_cap, slides_per_win = window_slides(
-            cfg.window_capacity, cfg.window_step)
-        win_example = _zeros_windows(
-            cfg.max_windows, slide_cap * slides_per_win)
-        if self._split is not None and self._split.delta:
-            # the sink consumes the chunk-level SlideView, whose stream leaf
-            # is sized by the *chunk* — unknown until the first feed, so the
-            # window channel is allocated lazily (see _ensure_win_channel)
-            self._agg_win_ch: Optional[Channel] = None
-            self._win_sig = None
-            self._win_example = None
-        else:
-            self._win_sig = None
-            self._win_example = win_example
-            self._agg_win_ch = self._on_device(
-                channel.make_channel(win_example, channel_capacity),
-                self.final)
-        up_out_cap = min(cfg.intermediate_cap, cfg.out_cap)
-        self._out_ch: Dict[str, Channel] = {}
-        # per-edge payload examples are retained so a degraded rebuild can
-        # re-allocate fresh empty channels with identical shapes
-        self._pub_examples: Dict[str, Any] = {}
-        for name in self.upstream:
-            if self._split is not None:
-                spec = self._split.pub[name]
-                k = len(spec.cols)
-                if self._split.delta:
-                    table = (jnp.zeros((spec.slide_rows_cap, k + 2),
-                                       jnp.uint32),
-                             jnp.zeros((spec.slide_rows_cap,), bool))
-                else:
-                    table = (jnp.zeros((cfg.max_windows, spec.rows_cap, k),
-                                       jnp.uint32),
-                             jnp.zeros((cfg.max_windows, spec.rows_cap),
-                                       bool))
-                pub_example = (table, jnp.zeros((cfg.max_windows,), bool))
-            else:
-                pub_example = _zeros_publication(cfg.max_windows, up_out_cap)
-            self._pub_examples[name] = pub_example
-            self._out_ch[name] = self._on_device(
-                channel.make_channel(pub_example, channel_capacity),
-                self.final)
+        self._empty_channels()
 
         # --- one jitted step per operator (channel state donated where a
         # step owns channels; windows are shared across consumers and are
@@ -346,99 +273,61 @@ class PipelinedRuntime:
     def _edge_popped(self, edge: str) -> None:
         self._edge_stats[edge]["pops"] += 1
 
+    def _empty_channels(self) -> None:
+        """Allocate every sink-side channel empty, on the sink's device.  A
+        sink that takes the slide view gets its window channel on the first
+        feed (:meth:`_ensure_win_channel`): the view is as long as the
+        chunk."""
+        win_example = self.step.sink_example()
+        self._win_sig = None
+        self._agg_win_ch: Optional[Channel] = None
+        if win_example is not None:
+            self._agg_win_ch = self._on_device(
+                channel.make_channel(win_example, self.channel_capacity),
+                self.final)
+        self._out_ch: Dict[str, Channel] = {
+            n: self._on_device(
+                channel.make_channel(self.step.payload_example(n),
+                                     self.channel_capacity), self.final)
+            for n in self.upstream
+        }
+
     # -- stage implementations (each traces into its own XLA program) ----------
     def _windows_impl(self, chunk: TripleBatch):
-        """Source stage: the shared Aggregator front-end (merge + window).
-
-        Returns ``(sink payload, operator payload)``: the materialized
-        windows feed the aggregator's window channel while upstream steps
-        consume either the windows or — in incremental mode — the slide
-        view.  With a delta split sink, *both* sides consume the view and
-        the windows are never materialized at all.
-        """
-        cfg = self.config
-        merged = merge_streams([chunk])
-        view = count_slides(
-            merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        if self._split is not None and self._split.delta:
-            return view, view
-        windows = windows_from_slides(
-            view, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        return windows, (view if cfg.incremental else windows)
+        """Source stage: merge and window, ``(sink payload, operator
+        payload)`` (:meth:`DagStep.source`)."""
+        return self.step.source(chunk)
 
     def _op_impl(
-        self, name: str, win_or_view, kb: Optional[KnowledgeBase],
+        self, name: str, op_in, kb: Optional[KnowledgeBase],
         env: Dict[str, jax.Array], with_stats: bool = False,
     ):
-        """Enrichment operator step: engine over this tick's windows (or
-        slide view, in incremental mode).  With ``with_stats`` (a separate
-        jitted twin) the publication is returned alongside a flat dict of
-        chunk-scalar engine metrics — the publication pushed onto the
-        channel is unchanged either way."""
-        op = self.operators[name]
-        if self._split is not None:
-            spec = self._split.pub[name]
-            if self._split.delta:
-                res = op.process_slide_tables(
-                    win_or_view, spec.cols, spec.slide_rows_cap, kb, env,
-                    with_stats)
-            else:
-                res = op.process_window_tables(
-                    win_or_view, spec.cols, spec.rows_cap, kb, env,
-                    with_stats)
-            if with_stats:
-                table, ovf, stats = res
-            else:
-                table, ovf = res
-            if ovf.ndim == 0:     # delta tables are chunk-level
-                ovf = jnp.broadcast_to(ovf, (self.config.max_windows,))
-            if with_stats:
-                return (table, ovf), stats
-            return table, ovf
-        if isinstance(win_or_view, SlideView):
-            res = op.process_slides(win_or_view, kb, env, with_stats)
-        else:
-            res = op.process_windows(win_or_view, kb, env, with_stats)
+        """Enrichment operator step (:meth:`DagStep.upstream`).  With
+        ``with_stats`` (a separate jitted twin) the publication is returned
+        alongside a flat dict of chunk-scalar engine metrics — the
+        publication pushed onto the channel is unchanged either way."""
+        pub, ovf, stats = self.step.upstream(name, op_in, kb, env, with_stats)
         if with_stats:
-            out_w, ovf, stats = res
-            return (out_w, ovf), stats
-        return res
+            return (pub, ovf), stats
+        return pub, ovf
 
     def _sink_impl(
         self, win_ch: Channel, out_chs: Dict[str, Channel],
         kb: Optional[KnowledgeBase], env: Dict[str, jax.Array],
         with_stats: bool = False,
     ):
-        """Aggregation operator step: pop every inbound edge, join, publish."""
-        win_ch, sink_payload, has = channel.pop(win_ch)
-        final_op = self.operators[self.final]
+        """Aggregation operator step: pop every inbound edge, then
+        :meth:`DagStep.sink`; a slot a pop found empty publishes nothing."""
+        win_ch, sink_in, has = channel.pop(win_ch)
+        payloads: Dict[str, Any] = {}
         overflow: Dict[str, jax.Array] = {}
-        if self._split is not None:
-            tables: Dict[str, Tuple[jax.Array, jax.Array]] = {}
-            for name in self.upstream:
-                out_chs[name], (table, ovf), h = channel.pop(out_chs[name])
-                tables[name] = table
-                overflow[name] = ovf & h
-            if self._split.delta:
-                res = final_op.process_sink_slides(
-                    sink_payload, tables, kb, env, with_stats)
-            else:
-                res = final_op.process_sink_windows(
-                    sink_payload, tables, kb, env, with_stats)
-        else:
-            upstream_out: Dict[str, TripleBatch] = {}
-            for name in self.upstream:
-                out_chs[name], (tb, ovf), h = channel.pop(out_chs[name])
-                upstream_out[name] = tb
-                overflow[name] = ovf & h
-            aug = augment_windows(self.dag, sink_payload, upstream_out)
-            res = final_op.process_windows(aug, kb, env, with_stats)
-        if with_stats:
-            out_w, ovf_f, stats = res
-        else:
-            out_w, ovf_f = res
+        for name in self.upstream:
+            out_chs[name], (payloads[name], ovf), h = channel.pop(
+                out_chs[name])
+            overflow[name] = ovf & h
+        out, ovf_f, stats = self.step.sink(sink_in, payloads, kb, env,
+                                           with_stats)
         overflow[self.final] = ovf_f & has
-        out = final_op._publish(out_w)
         out = out._replace(valid=out.valid & has)
         if with_stats:
             return win_ch, out_chs, out, overflow, stats
@@ -451,8 +340,8 @@ class PipelinedRuntime:
 
     def _ensure_win_channel(self, payload) -> None:
         """Lazily allocate the sink's window channel from the first payload
-        (split-delta mode ships the SlideView, whose stream leaf is sized by
-        the chunk — unknown at construction time)."""
+        (a slide-table sink takes the SlideView, whose stream leaf is sized
+        by the chunk — unknown at construction time)."""
         sig = tuple((leaf.shape, leaf.dtype) for leaf in jax.tree.leaves(payload))
         if self._agg_win_ch is None:
             example = jax.tree.map(jnp.zeros_like, payload)
@@ -854,19 +743,7 @@ class PipelinedRuntime:
         again), so the channels are rebuilt empty, every non-emitted seq is
         re-fed from the replay buffer, and degraded seqs are evaluated
         through the channel-free fallback program instead."""
-        if self._win_example is None:
-            self._agg_win_ch = None          # lazy split-delta: re-sized on
-            self._win_sig = None             # the next source dispatch
-        else:
-            self._agg_win_ch = self._on_device(
-                channel.make_channel(self._win_example, self.channel_capacity),
-                self.final)
-        self._out_ch = {
-            n: self._on_device(
-                channel.make_channel(self._pub_examples[n],
-                                     self.channel_capacity), self.final)
-            for n in self.upstream
-        }
+        self._empty_channels()
         self._edge_stats = copy_edge_stats(ck.edge_stats)
         for e in self._edge_stats.values():
             e["pushes"] = e["pops"]          # rebuilt channels are empty
@@ -913,48 +790,14 @@ class PipelinedRuntime:
             self._restore_full(ck)
 
     # -- graceful degradation: the channel-free fallback program --------------
-    def _fallback_impl(self, chunk: TripleBatch, kbs, envs):
-        """The pipeline's per-chunk computation with the channels cut out:
-        windows → every upstream step → sink join → publish, composed from
-        the *same* stage implementations in one program.  For a real chunk
-        every pop-validity mask in :meth:`_sink_impl` is True, so omitting
-        them here is value-identical — degraded output matches the piped
-        (and monolithic) bytes exactly."""
-        sink_payload, op_payload = self._windows_impl(chunk)
-        final_op = self.operators[self.final]
-        overflow: Dict[str, jax.Array] = {}
-        if self._split is not None:
-            tables: Dict[str, Any] = {}
-            for name in self.upstream:
-                table, ovf = self._op_impl(
-                    name, op_payload, kbs[name], envs[name])
-                tables[name] = table
-                overflow[name] = ovf
-            if self._split.delta:
-                out_w, ovf_f = final_op.process_sink_slides(
-                    sink_payload, tables, kbs[self.final], envs[self.final])
-            else:
-                out_w, ovf_f = final_op.process_sink_windows(
-                    sink_payload, tables, kbs[self.final], envs[self.final])
-        else:
-            upstream_out: Dict[str, TripleBatch] = {}
-            for name in self.upstream:
-                tb, ovf = self._op_impl(
-                    name, op_payload, kbs[name], envs[name])
-                upstream_out[name] = tb
-                overflow[name] = ovf
-            aug = augment_windows(self.dag, sink_payload, upstream_out)
-            out_w, ovf_f = final_op.process_windows(
-                aug, kbs[self.final], envs[self.final])
-        overflow[self.final] = ovf_f
-        out = final_op._publish(out_w)
-        return out, overflow
-
     def _run_fallback(self, seq: int):
-        """Evaluate one degraded seq through the fallback program (compiled
-        on first degradation; the happy path never builds it)."""
+        """Evaluate one degraded seq through the fallback program: the
+        chunk step with the channels cut out, the very program the single
+        program runtime jits — degraded output matches the piped (and
+        monolithic) bytes exactly.  Compiled on first degradation; the happy
+        path never builds it."""
         if self._fallback_step is None:
-            self._fallback_step = jax.jit(self._fallback_impl)
+            self._fallback_step = jax.jit(self.step)
         chunk = self._retained[seq]
         kbs = {n: op.kb for n, op in self.operators.items()}
         envs = {n: op.env for n, op in self.operators.items()}

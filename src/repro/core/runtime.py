@@ -32,7 +32,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.obs.metrics import finalize_stats, merge_stats
 from repro.obs.trace import Tracer, span_or_null
 
-from .engine import Plan, run_plan_windows
+from .engine import (
+    Plan, run_plan_slide_tables, run_plan_slides, run_plan_window_tables,
+    run_plan_windows,
+)
 from .kb import KnowledgeBase, collect_kb_stats, pad_to
 from .operator import OperatorConfig, SCEPOperator
 from .planner import (
@@ -176,7 +179,7 @@ def build_operators(
 
 
 # --------------------------------------------------------------------------
-# split aggregation sink (see planner.split_agg_plan / engine's sink runners)
+# the DAG's chunk step: source -> upstream operators -> aggregation sink
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -192,12 +195,10 @@ class PubSpec:
 @dataclasses.dataclass(frozen=True)
 class SplitSink:
     """A successfully split aggregation sink: the rewritten plan plus the
-    per-upstream table publication specs.  ``delta=True`` routes the sink
-    through the span-tagged slide path (one sink-chain pass per chunk)."""
+    per-upstream table publication specs."""
 
     plan: Plan
     pub: Dict[str, PubSpec]
-    delta: bool
 
 
 def prepare_split_sink(
@@ -225,13 +226,10 @@ def prepare_split_sink(
     if res is None:
         return None
     plan, pub_vars = res
-    delta = False
-    if config.incremental:
-        if not all(plan_supports_delta(operators[u].plan) for u in pub_vars):
-            return None
-        if not plan_supports_delta(plan):
-            return None
-        delta = True
+    if config.incremental and not all(
+            plan_supports_delta(p) for p in
+            [plan] + [operators[u].plan for u in pub_vars]):
+        return None
     pub = {
         u: PubSpec(
             vars=names,
@@ -241,39 +239,206 @@ def prepare_split_sink(
         )
         for u, names in pub_vars.items()
     }
-    return SplitSink(plan=plan, pub=pub, delta=delta)
+    return SplitSink(plan=plan, pub=pub)
 
 
-def augment_windows(
-    dag: OperatorDAG, windows: Windows, upstream_out: Dict[str, TripleBatch]
-) -> Windows:
-    """Append upstream operator outputs to the very window that produced them.
+def _zero_windows(num_windows: int, capacity: int) -> TripleBatch:
+    """Zeros shaped like ``num_windows`` windows of ``capacity`` triples."""
+    return jax.tree.map(
+        lambda col: jnp.zeros((num_windows,) + col.shape, col.dtype),
+        empty_triples(capacity))
 
-    Window alignment is what makes decomposed == monolithic (paper: "All
-    results are the same"); the concatenation order follows the final
-    sub-query's declared inputs so every execution mode is bit-identical.
+
+class DagStep:
+    """The operator DAG's chunk step, and the one place that decides the
+    sink's format.
+
+    Built once per registration from ``(dag, operators, config, mesh)``.
+    The single-program runtime jits :meth:`__call__`; the pipelined runtime
+    runs :meth:`source`, :meth:`upstream` and :meth:`sink` as its stages,
+    cut at channel boundaries, and jits :meth:`__call__` as its degraded
+    fallback.  The sink takes one of three formats:
+
+    * *augmented windows* (``split is None``): upstream publications are
+      appended to the very window that produced them and the sink's plan
+      decodes them again — window alignment is what makes decomposed ==
+      monolithic (paper: "All results are the same").  Kept for plans
+      outside the split fragment, and under a mesh;
+    * *window tables*: upstreams publish per-window binding tables that the
+      rewritten sink plan joins directly (:func:`prepare_split_sink`);
+    * *slide tables* (window tables with ``slides``): one span-tagged table
+      per upstream per chunk, joined on the delta path.
+
+    ``slides``: upstream operators consume the chunk's slide view (delta
+    evaluation, incremental mode) rather than materialized windows; a mesh
+    turns it off, since windows must be materialized to shard.
     """
-    parts = [windows.triples] + [
-        upstream_out[src]
-        for src in dag.subqueries[dag.final].inputs
-        if src != "stream"
-    ]
-    aug = TripleBatch(
-        *(jnp.concatenate(cols, axis=-1) for cols in zip(*parts))
-    )
-    return Windows(aug, windows.window_valid)
+
+    def __init__(
+        self, dag: OperatorDAG, operators: Dict[str, SCEPOperator],
+        config: RuntimeConfig, mesh: Optional[Mesh] = None,
+        data_axis: str = "data",
+    ):
+        self.__name__ = "dag_step"     # the jitted program's name in traces
+        self.dag = dag
+        self.operators = operators
+        self.config = config
+        self.mesh = mesh
+        self.data_axis = data_axis
+        self.final = dag.final
+        # DAG insertion order: every runtime evaluates (and pairs channels
+        # with) the upstream operators in this order
+        self.upstream_names: List[str] = [
+            n for n in dag.subqueries if n != dag.final]
+        self.split = prepare_split_sink(dag, operators, config, mesh)
+        if self.split is not None:
+            # every introspection surface (EXPLAIN, plan_caps, last_stats)
+            # reports the plan that actually runs
+            operators[dag.final].plan = self.split.plan
+        self.slides = config.incremental and mesh is None
+        self._slide_cap, self._r = window_slides(config.window_capacity,
+                                                 config.window_step)
+
+    def _geometry(self):
+        cfg = self.config
+        return cfg.window_capacity, cfg.max_windows, cfg.window_step
+
+    def source(self, chunk: TripleBatch):
+        """Merge, then window: ``(sink_in, op_in)``, what the sink and the
+        upstream operators consume — the same windows, or the slide view
+        for the upstreams (materialized windows for an augmented sink,
+        the view itself for a slide-table sink)."""
+        merged = merge_streams([chunk])
+        if not self.slides:
+            windows = count_windows(merged, *self._geometry())
+            if self.mesh is not None:
+                windows = shard_windows(windows, self.mesh, self.data_axis)
+            return windows, windows
+        view = count_slides(merged, *self._geometry())
+        if self.split is not None:
+            return view, view
+        return windows_from_slides(view, *self._geometry()), view
+
+    def upstream(
+        self, name: str, op_in, kb: Optional[KnowledgeBase],
+        env: Dict[str, jax.Array], with_stats: bool = False,
+    ):
+        """Upstream operator ``name`` on its :meth:`source` output.
+
+        Returns ``(publication, overflow [W], stats)``: the publication is
+        ``[W, out_cap]`` triples for an augmented sink, else the binding
+        table the sink joins; ``stats`` is None unless ``with_stats``.
+        """
+        cfg = self.config
+        plan = self.operators[name].plan
+        if self.split is not None:
+            spec = self.split.pub[name]
+            if self.slides:
+                res = run_plan_slide_tables(
+                    plan, op_in, spec.cols, spec.slide_rows_cap, self._r,
+                    kb, env, with_stats)
+            else:
+                res = run_plan_window_tables(
+                    plan, op_in, spec.cols, spec.rows_cap, kb, env,
+                    with_stats)
+        elif self.slides and plan_supports_delta(plan):
+            res = run_plan_slides(plan, op_in, self._r, cfg.max_windows, kb,
+                                  env, with_stats)
+        else:
+            if self.slides:
+                # not delta-safe (OPTIONAL): recompute per window on the
+                # materialized slides — the same output bits
+                op_in = windows_from_slides(op_in, *self._geometry())
+            res = run_plan_windows(plan, op_in, kb, env, with_stats)
+        pub, ovf = res[0], res[1]
+        if ovf.ndim == 0:
+            # slide tables are chunk-level: broadcast the flag to the
+            # per-window convention every overflow consumer expects
+            ovf = jnp.broadcast_to(ovf, (cfg.max_windows,))
+        return pub, ovf, (res[2] if with_stats else None)
+
+    def sink(
+        self, sink_in, payloads: Dict[str, Any], kb: Optional[KnowledgeBase],
+        env: Dict[str, jax.Array], with_stats: bool = False,
+    ):
+        """The aggregation sink on its :meth:`source` output and every
+        upstream's publication: augment or table-join, finalize, publish.
+        Returns ``(published chunk, overflow [W], stats)``."""
+        cfg = self.config
+        plan = self.operators[self.final].plan
+        if self.split is None:
+            # the concatenation order follows the final sub-query's
+            # declared inputs so every execution mode is bit-identical
+            parts = [sink_in.triples] + [
+                payloads[src] for src in self.dag.subqueries[self.final].inputs
+                if src != "stream"]
+            aug = Windows(
+                TripleBatch(*(jnp.concatenate(cols, axis=-1)
+                              for cols in zip(*parts))),
+                sink_in.window_valid)
+            res = run_plan_windows(plan, aug, kb, env, with_stats)
+        elif self.slides:
+            res = run_plan_slides(plan, sink_in, self._r, cfg.max_windows,
+                                  kb, env, with_stats, tables=payloads)
+        else:
+            res = run_plan_windows(plan, sink_in, kb, env, with_stats,
+                                   tables=payloads)
+        out = self.operators[self.final]._publish(res[0])
+        return out, res[1], (res[2] if with_stats else None)
+
+    def payload_example(self, name: str):
+        """Zeros shaped like upstream ``name``'s ``(publication, overflow)``:
+        one slot of its channel into the sink."""
+        w = self.config.max_windows
+        ovf = jnp.zeros((w,), bool)
+        if self.split is None:
+            return _zero_windows(w, self.operators[name].plan.out_cap), ovf
+        spec = self.split.pub[name]
+        if self.slides:          # + the two span columns, once per chunk
+            rows, k = (spec.slide_rows_cap,), len(spec.cols) + 2
+        else:
+            rows, k = (w, spec.rows_cap), len(spec.cols)
+        return (jnp.zeros(rows + (k,), jnp.uint32),
+                jnp.zeros(rows, bool)), ovf
+
+    def sink_example(self) -> Optional[Windows]:
+        """Zeros shaped like :meth:`source`'s ``sink_in``, or None where that
+        is the slide view, whose stream is as long as the chunk."""
+        if self.slides and self.split is not None:
+            return None
+        w = self.config.max_windows
+        return Windows(_zero_windows(w, self._slide_cap * self._r),
+                       jnp.zeros((w,), bool))
+
+    def __call__(
+        self, chunk: TripleBatch, kbs: Dict[str, Optional[KnowledgeBase]],
+        envs: Dict[str, Dict[str, jax.Array]], with_stats: bool = False,
+    ):
+        """One chunk through the whole DAG: ``(published chunk, overflow)``
+        with ``overflow[op]`` a ``[W]`` flag per operator, plus per-operator
+        stats when ``with_stats``."""
+        sink_in, op_in = self.source(chunk)
+        payloads: Dict[str, Any] = {}
+        overflow: Dict[str, jax.Array] = {}
+        stats: Dict[str, Dict[str, jax.Array]] = {}
+        for name in self.upstream_names:
+            payloads[name], overflow[name], stats[name] = self.upstream(
+                name, op_in, kbs[name], envs[name], with_stats)
+        out, overflow[self.final], stats[self.final] = self.sink(
+            sink_in, payloads, kbs[self.final], envs[self.final], with_stats)
+        if with_stats:
+            return out, overflow, stats
+        return out, overflow
 
 
 class DSCEPRuntime:
     """Executes a decomposed query DAG over chunked input streams.
 
-    The whole DAG traces into **one** XLA program per chunk shape: upstream
-    sub-queries are independent dataflow branches (inter-operator parallelism
-    — XLA schedules them concurrently), windows are the vmapped/shardable
-    unit (intra-operator parallelism), and intermediate results stay
-    **window-aligned**: operator G sees upstream outputs appended to the very
-    window that produced them, which is what makes decomposed and monolithic
-    results identical (paper: "All results are the same").
+    The whole DAG traces into **one** XLA program per chunk shape — the
+    jitted :class:`DagStep`: upstream sub-queries are independent dataflow
+    branches (inter-operator parallelism — XLA schedules them
+    concurrently), windows are the vmapped/shardable unit (intra-operator
+    parallelism), and intermediate results stay **window-aligned**.
     """
 
     def __init__(
@@ -293,22 +458,15 @@ class DSCEPRuntime:
         self.data_axis = data_axis
         self.vocab = vocab
         self.operators = build_operators(dag, kb, config, tracer)
-        # split aggregation sink: upstream operators ship binding tables,
-        # the sink joins them directly (None -> augmented-window path).
-        # The sink operator's plan is swapped for the rewritten one so
-        # every introspection surface (EXPLAIN, plan_caps, last_stats)
-        # reports the plan that actually runs.
         with span_or_null(tracer, "dscep.split_sink"):
-            self._split = prepare_split_sink(dag, self.operators, config,
-                                             mesh)
-        if self._split is not None:
-            self.operators[dag.final].plan = self._split.plan
-        self._jit_chunk = jax.jit(self._dag_impl)
+            self.step = DagStep(dag, self.operators, config, mesh, data_axis)
+        self._split = self.step.split
+        self._jit_chunk = jax.jit(self.step)
         self.tracer = tracer
         self._seq = 0
         self._collect = bool(tracer is not None and tracer.config.metrics)
         self._jit_chunk_stats = (
-            jax.jit(functools.partial(self._dag_impl, with_stats=True))
+            jax.jit(functools.partial(self.step, with_stats=True))
             if self._collect else None)
         # lifetime device-side accumulators (host syncs only in reports)
         self._overflow_acc: Dict[str, jax.Array] = {
@@ -317,122 +475,6 @@ class DSCEPRuntime:
         self._stats_acc: Dict[str, Dict[str, jax.Array]] = {
             n: {} for n in self.operators
         }
-
-    # -- the single-program DAG step -----------------------------------------
-    def _dag_impl(
-        self, chunk: TripleBatch, kbs: Dict[str, Optional[KnowledgeBase]],
-        envs: Dict[str, Dict[str, jax.Array]], with_stats: bool = False,
-    ):
-        cfg = self.config
-        merged = merge_streams([chunk])
-        if self._split is not None:
-            return self._dag_impl_split(merged, kbs, envs, with_stats)
-        view = None
-        if cfg.incremental and self.mesh is None:
-            # delta evaluation needs the slide view; the materialized
-            # windows still feed the aggregator (upstream outputs are
-            # window-aligned batches with no slide structure to delta over)
-            view = count_slides(
-                merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-            windows = windows_from_slides(
-                view, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        else:
-            windows = count_windows(
-                merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        if self.mesh is not None:
-            windows = shard_windows(windows, self.mesh, self.data_axis)
-
-        overflow: Dict[str, jax.Array] = {}
-        stats: Dict[str, Dict[str, jax.Array]] = {}
-        final = self.dag.final
-        upstream_out: Dict[str, TripleBatch] = {}
-        for name in self.dag.subqueries:
-            if name == final:
-                continue
-            if view is not None:
-                res = self.operators[name].process_slides(
-                    view, kbs[name], envs[name], with_stats
-                )
-            else:
-                res = self.operators[name].process_windows(
-                    windows, kbs[name], envs[name], with_stats
-                )
-            if with_stats:
-                out_w, ovf, stats[name] = res
-            else:
-                out_w, ovf = res
-            upstream_out[name] = out_w
-            overflow[name] = ovf
-
-        # window-aligned augmentation for the aggregation operator
-        aug_windows = augment_windows(self.dag, windows, upstream_out)
-        res = self.operators[final].process_windows(
-            aug_windows, kbs[final], envs[final], with_stats
-        )
-        if with_stats:
-            out_w, ovf, stats[final] = res
-        else:
-            out_w, ovf = res
-        overflow[final] = ovf
-        out = self.operators[final]._publish(out_w)
-        if with_stats:
-            return out, overflow, stats
-        return out, overflow
-
-    def _dag_impl_split(
-        self, merged: TripleBatch, kbs, envs, with_stats: bool = False,
-    ):
-        """The split-sink DAG step: upstream operators produce binding
-        *tables* (windowed or span-tagged), the sink joins them via its
-        rewritten BindingJoin plan over the raw windows — no augmented
-        window, no binding-graph decode scans."""
-        cfg = self.config
-        split = self._split
-        final = self.dag.final
-        overflow: Dict[str, jax.Array] = {}
-        stats: Dict[str, Dict[str, jax.Array]] = {}
-        tables: Dict[str, Tuple[jax.Array, jax.Array]] = {}
-        if split.delta:
-            view = count_slides(
-                merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        else:
-            windows = count_windows(
-                merged, cfg.window_capacity, cfg.max_windows, cfg.window_step)
-        for name in self.dag.subqueries:
-            if name == final:
-                continue
-            spec = split.pub[name]
-            if split.delta:
-                res = self.operators[name].process_slide_tables(
-                    view, spec.cols, spec.slide_rows_cap,
-                    kbs[name], envs[name], with_stats)
-            else:
-                res = self.operators[name].process_window_tables(
-                    windows, spec.cols, spec.rows_cap,
-                    kbs[name], envs[name], with_stats)
-            if with_stats:
-                tables[name], ovf, stats[name] = res
-            else:
-                tables[name], ovf = res
-            # delta tables are chunk-level: broadcast the scalar flag to the
-            # per-window convention every overflow consumer expects
-            overflow[name] = (jnp.broadcast_to(ovf, (cfg.max_windows,))
-                              if ovf.ndim == 0 else ovf)
-        if split.delta:
-            res = self.operators[final].process_sink_slides(
-                view, tables, kbs[final], envs[final], with_stats)
-        else:
-            res = self.operators[final].process_sink_windows(
-                windows, tables, kbs[final], envs[final], with_stats)
-        if with_stats:
-            out_w, ovf_f, stats[final] = res
-        else:
-            out_w, ovf_f = res
-        overflow[final] = ovf_f
-        out = self.operators[final]._publish(out_w)
-        if with_stats:
-            return out, overflow, stats
-        return out, overflow
 
     # -- orchestration ---------------------------------------------------------
     def process_chunk(self, chunk: TripleBatch) -> Tuple[TripleBatch, Dict[str, jax.Array]]:

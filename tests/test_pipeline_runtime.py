@@ -11,7 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core import paper_queries as PQ
+from repro.core.faults import FaultEvent, FaultPlan
 from repro.core.rdf import Vocab, to_host_rows
+from repro.core.recovery import RecoveryConfig
 from repro.core.session import ExecutionConfig, Session
 from repro.data.dbpedia import KBConfig, generate_kb
 from repro.data.tweets import (
@@ -93,6 +95,63 @@ def test_pipelined_bit_identical_to_single_program(pworld, qname):
         res_p += sorted(set((r[0], r[1], r[2]) for r in to_host_rows(o)))
     assert len(res_p) > 0
     assert sorted(res_m) == sorted(res_p)
+
+
+# Q15 with an OPTIONAL stream pattern, which lands in the sink's plan
+Q15_OPTIONAL_RQ = PQ.Q15_RQ.replace(
+    "  ?tweet schema:mentions ?ent .\n",
+    "  ?tweet schema:mentions ?ent .\n"
+    "  OPTIONAL { ?tweet schema:likes ?likes . }\n")
+
+# sink format -> (query text, config changes) that selects it
+FORMATS = {
+    # OPTIONAL is not delta-safe, so no slide tables: the upstream runs
+    # delta on the slide view, the sink decodes its triples from windows
+    "augmented": (Q15_OPTIONAL_RQ, dict(incremental=True, window_step=48)),
+    "window_tables": (PQ.Q15_RQ, {}),
+    "slide_tables": (PQ.Q15_RQ, dict(incremental=True, window_step=48)),
+}
+
+
+def sink_format(step):
+    if step.split is None:
+        return "augmented"
+    return "slide_tables" if step.slides else "window_tables"
+
+
+@pytest.mark.parametrize("fmt", sorted(FORMATS))
+def test_dag_step_format_is_one_program_across_runtimes(pworld, fmt):
+    """DagStep picks the sink's format from the plan and the config; the
+    single program, the pipelined stages and the pipelined runtime's
+    degraded fallback then publish the same bytes, and the fallback
+    compiles the single program's very chunk step."""
+    q, over = FORMATS[fmt]
+    cfg = CFG.replace(**over)
+    single = Session(cfg.replace(mode="single_program"), vocab=pworld.vocab,
+                     kb=pworld.kbd.kb).register(q)
+    # chunk 1 crashes the source stage and, with no restart allowed, is
+    # evaluated by the fallback; the other chunks run through the channels
+    piped = Session(
+        cfg.replace(mode="pipelined",
+                    faults=FaultPlan((FaultEvent("crash_stage", "source", 1),)),
+                    recovery=RecoveryConfig(checkpoint_every=0,
+                                            max_restarts=0)),
+        vocab=pworld.vocab, kb=pworld.kbd.kb).register(q)
+    rt_s, rt_p = single.runtime, piped.runtime
+    assert sink_format(rt_s.step) == sink_format(rt_p.step) == fmt
+    outs_s, ovf_s = single.run(pworld.chunks)
+    outs_p, ovf_p = piped.run(pworld.chunks)
+    assert piped.last_stats["recovery"]["degraded_chunks"] == [1]
+    assert any(np.asarray(o.valid).any() for o in outs_s)
+    assert_bit_identical(outs_s, outs_p, fmt)
+    assert ovf_p == ovf_s
+    kbs = {n: op.kb for n, op in rt_s.operators.items()}
+    envs = {n: op.env for n, op in rt_s.operators.items()}
+
+    def jp(fn):
+        return str(jax.make_jaxpr(fn)(pworld.chunks[0], kbs, envs))
+
+    assert jp(rt_p._fallback_step) == jp(rt_s._jit_chunk)
 
 
 def test_schedule_keeps_two_chunks_in_flight(pworld):
